@@ -58,7 +58,7 @@ def _benchmarks():
 
 # DSE entries rerun fault injection many times; the batched-vs-sequential
 # comparison deliberately includes a slow sequential arm.  serve_scaling
-# spawns one fresh-compile subprocess per (config, policy, device-count) arm.
+# compiles one sharded engine per (config, policy, device-count) arm.
 FAST_SKIP = {"fig15_table2_dse", "dse_batched_vs_sequential", "fat_dse",
              "serve_scaling"}
 
@@ -72,11 +72,23 @@ def main() -> None:
     benches = _benchmarks()
     if args.only:
         benches = {k: v for k, v in benches.items() if args.only in k}
+    if args.fast:
+        benches = {k: v for k, v in benches.items() if k not in FAST_SKIP}
+    if "serve_scaling" in benches:
+        if len(benches) > 1:
+            # its host devices and excess-precision pin would change every
+            # other entry's backend: it runs only when selected alone
+            del benches["serve_scaling"]
+            print("# serve_scaling skipped: run it alone "
+                  "(--only serve_scaling)")
+        else:
+            # its 1/2/4-device arms run in this process: the CPU backend
+            # needs its host devices before JAX starts it
+            from benchmarks.serve_bench import pin_scaling_flags
+            pin_scaling_flags()
     out = {}
     print("name,us_per_call,derived")
     for name, fn in benches.items():
-        if args.fast and name in FAST_SKIP:
-            continue
         import jax
         jax.clear_caches()  # each fig compiles many distinct FT configs
         t0 = time.time()

@@ -17,22 +17,20 @@ at temperature 0) outputs — that equality is enforced by
 tests/test_serve_engine.py; this benchmark measures the speed side.
 
 ``serve_scaling`` measures sharded-serving throughput 1 -> N devices
-(dense vs MoE, clean vs crt3).  Each arm runs in a subprocess under
-``--xla_force_host_platform_device_count=N`` with a pure-DP (N, 1) mesh and
-a batch that grows with the device count — **weak scaling**: on the
-host-platform backend all N "devices" share the same cores, so per-device
-work is held constant and throughput rises as the batch amortizes the
-fixed per-step dispatch overhead.  On real accelerators the same harness
-measures strong scaling; the snapshot's meta block records which regime
-produced it.
+(dense vs MoE, clean vs crt3).  All arms run in this one process, each on
+a pure-DP (N, 1) mesh over the first N of ``jax.devices()`` — one process
+holds the chips, as an accelerator requires — with a batch that grows with
+the device count.  On the CPU backend (``--scaling`` gives it four host
+devices) that is **weak scaling**: all N "devices" share the same cores, so
+per-device work is held constant and throughput rises as the batch
+amortizes the fixed per-step dispatch overhead.  The snapshot's meta block
+records the regime.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
-import subprocess
-import sys
-import textwrap
 import time
 
 import jax
@@ -40,6 +38,7 @@ import jax.numpy as jnp
 
 from repro import ft
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import build
 from repro.serve.engine import Engine, ServeConfig
 from repro.serve.scheduler import Request, Scheduler, SchedulerConfig
@@ -151,16 +150,9 @@ SCALE_CONFIGS = (("dense", "h2o-danube-1.8b"), ("moe", "qwen3-moe-235b-a22b"))
 SCALE_BASE_BATCH = 4
 SCALE_REPS = 3
 
-_SCALE_WORKER = """
-    import dataclasses, json, time
-    import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import Mesh
-    from repro import ft
-    from repro.configs import get_config
-    from repro.models import build
-    from repro.serve.engine import Engine, ServeConfig
 
-    arch, pname, devices = {arch!r}, {policy!r}, {devices}
+
+def _scale_arm(arch, pname, devices):
     cfg = get_config(arch, reduced=True)
     if cfg.moe is not None:
         # capacity is per-shard: give headroom so no partitioning drops
@@ -168,52 +160,37 @@ _SCALE_WORKER = """
             cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    mesh = Mesh(np.array(jax.devices()).reshape(devices, 1),
-                ("data", "model"))
-    B = {base_batch} * devices                     # weak scaling
-    batch = {{"tokens": jax.random.randint(jax.random.PRNGKey(1),
-                                           (B, {prompt}), 0, cfg.vocab)}}
-    policy = (None if pname is None
-              else ft.get_policy(pname, ber=1e-3, weight_faults=False))
+    mesh = make_mesh((devices, 1), ("data", "model"),
+                     devices=jax.devices()[:devices])
+    B = SCALE_BASE_BATCH * devices                  # weak scaling
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1),
+                                          (B, PROMPT), 0, cfg.vocab)}
     eng = Engine(model, params, mesh=mesh,
-                 cfg=ServeConfig(max_new_tokens={new}), policy=policy)
+                 cfg=ServeConfig(max_new_tokens=NEW), policy=_policy(pname))
     jax.block_until_ready(eng.generate(batch, seed=0))      # compile
     rates = []
-    for r in range({reps}):
+    for r in range(SCALE_REPS):
         t0 = time.perf_counter()
         jax.block_until_ready(eng.generate(batch, seed=r))
         rates.append(eng.stats.tokens / (time.perf_counter() - t0))
-    print(json.dumps({{"tok_s": sorted(rates)[len(rates) // 2]}}))
-"""
-
-
-def _scale_worker(arch, policy, devices):
-    env = dict(os.environ)
-    # same env the determinism battery documents for sharded serving, so the
-    # measured executable is the one whose outputs the tests pin down
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={devices} "
-                        "--xla_allow_excess_precision=false")
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    code = textwrap.dedent(_SCALE_WORKER.format(
-        arch=arch, policy=policy, devices=devices,
-        base_batch=SCALE_BASE_BATCH, prompt=PROMPT, new=NEW,
-        reps=SCALE_REPS))
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, timeout=1800, env=env)
-    if out.returncode != 0:
-        raise RuntimeError(f"serve_scaling worker {arch}/{policy}/"
-                           f"{devices}dev failed:\n{out.stderr[-2000:]}")
-    return json.loads(out.stdout.strip().splitlines()[-1])["tok_s"]
+    return sorted(rates)[len(rates) // 2]
 
 
 def serve_scaling():
     """Tokens/sec 1 -> N devices for the sharded Engine (weak scaling on the
     host-platform backend; see module docstring)."""
+    if len(jax.devices()) < max(SCALE_DEVICES):
+        raise RuntimeError(
+            f"serve_scaling needs {max(SCALE_DEVICES)} devices, found "
+            f"{len(jax.devices())}; on the CPU backend set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={max(SCALE_DEVICES)} "
+            "before JAX starts (python -m benchmarks.serve_bench --scaling "
+            "does)")
     rows = []
     derived = {}
     for fam, arch in SCALE_CONFIGS:
         for pname in POLICIES:
-            tps = [_scale_worker(arch, pname, d) for d in SCALE_DEVICES]
+            tps = [_scale_arm(arch, pname, d) for d in SCALE_DEVICES]
             label = f"{fam}_{pname or 'clean'}"
             for d, t in zip(SCALE_DEVICES, tps):
                 rows.append(dict(family=fam, policy=pname or "clean",
@@ -236,6 +213,9 @@ def scaling_snapshot(path="BENCH_serve_scaling.json"):
              "device count, so throughput rises by amortizing fixed "
              "per-step dispatch overhead; on real accelerators the same "
              "harness measures strong scaling",
+        backend=f"one process, {len(jax.devices())} "
+                f"{jax.devices()[0].platform} devices; an N-device arm "
+                "uses the first N",
         devices=list(SCALE_DEVICES), base_batch=SCALE_BASE_BATCH,
         prompt=PROMPT, new_tokens=NEW, mesh="(devices, 1) = (data, model)")
     with open(path, "w") as f:
@@ -245,6 +225,17 @@ def scaling_snapshot(path="BENCH_serve_scaling.json"):
     return path
 
 
+def pin_scaling_flags():
+    """Append, before JAX starts its backend, the CPU device count the
+    scaling arms need and the excess-precision pin of the sharded-serving
+    determinism battery (the measured executables are the ones whose
+    outputs the tests pin down)."""
+    os.environ["XLA_FLAGS"] = " ".join([
+        os.environ.get("XLA_FLAGS", ""),
+        f"--xla_force_host_platform_device_count={max(SCALE_DEVICES)}",
+        "--xla_allow_excess_precision=false"]).strip()
+
+
 if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
@@ -252,6 +243,7 @@ if __name__ == "__main__":
                     help="run serve_scaling and write BENCH_serve_scaling.json")
     args = ap.parse_args()
     if args.scaling:
+        pin_scaling_flags()
         p = scaling_snapshot()
         print(f"# wrote {p}")
         print(open(p).read())

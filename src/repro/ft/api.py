@@ -56,7 +56,6 @@ def protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
                    layer_protected: bool = True,
                    backend: str = "reference",
                    t: int | None = None,
-                   interpret: bool = True,
                    dyn=None) -> jax.Array:
     """Fault-tolerant linear: float in/out, faulty quantized DLA inside.
 
@@ -75,7 +74,6 @@ def protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
         layer is in the protected (sensitive) set.
       backend: "reference" | "fused" | "pallas".
       t: truncation LSB for the pallas backend (calibrated from x/w if None).
-      interpret: run the pallas/fused kernel in interpret mode (CPU).
       dyn: optional mapping of *traced* overrides for the policy's numeric
         protection knobs (``ib_th`` / ``nb_th`` / ``q_scale``).  The static
         values in ``policy`` are metadata the executable specializes on; a
@@ -94,7 +92,7 @@ def protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
         from repro.kernels.fused_decode.ops import fused_protect_linear
         return fused_protect_linear(key, x, w, policy, important,
                                     layer_protected=layer_protected,
-                                    dyn=dyn, interpret=interpret)
+                                    dyn=dyn)
     if getattr(key, "ndim", 1) == 2:
         raise ValueError("per-row key batches are only supported by "
                          "backend='reference' or backend='fused'")
@@ -105,8 +103,7 @@ def protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
                          "statically)")
     if backend == "pallas":
         return _protect_pallas(key, x, w, policy, important,
-                               layer_protected=layer_protected, t=t,
-                               interpret=interpret)
+                               layer_protected=layer_protected, t=t)
     raise ValueError(f"unknown backend {backend!r}; expected one of "
                      f"{BACKENDS}")
 
@@ -148,7 +145,7 @@ def protect_linear_ste(key: jax.Array, x: jax.Array, w: jax.Array,
     non-differentiable quantize/flip/truncate chain is treated as identity,
     so gradients flow and the network learns to place its decision margins
     where bit flips cannot reach them.  ``kw`` is forwarded verbatim
-    (``layer_protected`` / ``backend`` / ``t`` / ``interpret`` / ``dyn``).
+    (``layer_protected`` / ``backend`` / ``t`` / ``dyn``).
     """
     y = protect_linear(key, jax.lax.stop_gradient(x),
                        jax.lax.stop_gradient(w), policy, important, **kw)
@@ -254,8 +251,7 @@ def _pad_to(a: jax.Array, mults: tuple[int, ...]) -> jax.Array:
 
 
 def _protect_pallas(key, x, w, policy: ProtectionPolicy, important, *,
-                    layer_protected: bool, t: int | None, interpret: bool,
-                    block: int = 128):
+                    layer_protected: bool, t: int | None, block: int = 128):
     from repro.kernels.fault_inject.ops import random_planes
     from repro.kernels.protected_mm.kernel import protected_mm
 
@@ -299,7 +295,7 @@ def _protect_pallas(key, x, w, policy: ProtectionPolicy, important, *,
 
     yq = protected_mm(xq8, wq8, rnd_o, rnd_i, imp_p, t=t,
                       ber=float(policy.ber), ib=ib, nb=nb,
-                      bm=block, bn=block, bk=block, interpret=interpret)
+                      bm=block, bn=block, bk=block)
     scale = sx * sw * (2.0 ** t)
     y = yq[:x2.shape[0], :n].astype(jnp.float32) * scale
     return y.reshape(*orig_shape[:-1], n)

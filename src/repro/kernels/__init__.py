@@ -1,3 +1,17 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels of the protected datapath.
+
+Every kernel takes ``interpret=``: ``None`` (the default everywhere) follows
+the platform — interpreted on the CPU backend, compiled for the chip on
+every other.  Tests that compile for a described chip pass ``False``.
+"""
+from __future__ import annotations
+
+import jax
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret`` as given, or — for ``None`` — whether the default
+    backend is the CPU (the only place a Pallas TPU kernel is interpreted)."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return interpret

@@ -19,8 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import resolve_interpret
 
 
 def _kernel(x_ref, rnd_ref, prot_ref, o_ref, *, ber: float, bits: int):
@@ -41,7 +40,7 @@ def _kernel(x_ref, rnd_ref, prot_ref, o_ref, *, ber: float, bits: int):
 @functools.partial(jax.jit, static_argnames=("ber", "bits", "bm", "bn",
                                              "interpret"))
 def fault_inject(x, rnd, protect, ber: float, bits: int = 8,
-                 bm: int = 256, bn: int = 128, interpret: bool = True):
+                 bm: int = 256, bn: int = 128, interpret: bool | None = None):
     """x: (M,N) int32; rnd: (bits,M,N) uint32; protect: (N,) int32."""
     M, N = x.shape
     bm, bn = min(bm, M), min(bn, N)
@@ -57,7 +56,7 @@ def fault_inject(x, rnd, protect, ber: float, bits: int = 8,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, rnd, protect.reshape(1, N))

@@ -15,7 +15,7 @@ def random_planes(key, shape, bits: int = 8):
 
 @partial(jax.jit, static_argnames=("ber", "bits", "interpret"))
 def inject(key, x, protect, ber: float, bits: int = 8,
-           interpret: bool = True):
+           interpret: bool | None = None):
     """Inject faults into int8-window values x (M,N) at BER `ber`."""
     rnd = random_planes(key, x.shape, bits)
     return fault_inject(x, rnd, protect, ber, bits, interpret=interpret)

@@ -26,7 +26,14 @@ Differences from ``protected_mm`` that make this the serving kernel:
 
 Decode-shaped by design: the whole (M, N) accumulator lives in VMEM and the
 grid is sequential over K only, which assumes small M (a decode batch) and
-moderate N.  Prefill-sized GEMMs should keep using the tiled kernels.
+moderate N.  ``vmem_bytes`` is the kernel's VMEM plan for a padded shape;
+``fused_protect_linear`` sends a call whose plan exceeds ``VMEM_LIMIT`` to
+the bitwise-equal reference datapath instead.  At h2o-danube widths the
+plan admits up to M=96 rows at N=6912 (272 at N=2560, 1080 at N=640), so
+decode rows run the kernel, while 512-row prefill of the wide projections
+and their per-row weight faults (an (M, bk, N) int32 flip block) run the
+reference.  ``tests/test_tpu_compile.py`` checks the plan against the v5e
+compiler.
 """
 from __future__ import annotations
 
@@ -37,11 +44,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import resolve_interpret
 
 ACC_BITS = 24
 OUT_BITS = 8
+
+# The compiler's default scoped-VMEM limit on v5e ("limit 16.00M" in its
+# refusals); a kernel whose plan exceeds it does not compile.
+VMEM_LIMIT = 16 * 2**20
+
+
+def vmem_bytes(m: int, n: int, *, dppu_src: str = "none",
+               perrow_wf: bool = False, bk: int = 128) -> int:
+    """VMEM the kernel needs for tile-aligned M rows and N columns; K
+    streams through in ``bk``-row tiles and does not enter.
+
+    Blocks are double-buffered: x, w (and the clean w), the (M, N) int32
+    output flip words (and the DPPU flip words and mask), the int8 output
+    and the lane-padded t column.  The (M, N) int32 accumulator scratch is
+    single (two with a recompute from weights), the epilogue holds two
+    (M, N) int32 temporaries, and per-row weight faults add their
+    (M, bk, N) int32 flip block and faulty-weight product.
+    """
+    mn = m * n * 4
+    blocks = m * bk + bk * n + mn + m * n + m * 128 * 4
+    if dppu_src == "wcl":
+        blocks += bk * n
+    if dppu_src != "none":
+        blocks += mn + 8 * n * 4
+    wf = m * bk * n * 4 if perrow_wf else 0
+    scratch = mn * (2 if dppu_src in ("w", "wcl") else 1)
+    return 2 * (blocks + wf) + scratch + 2 * mn + wf
 
 
 def _sign_extend(u, bits):
@@ -136,7 +169,7 @@ def fused_decode(xq, wq, oflips, q_scale, *, wq_clean=None, wflips=None,
                  dflips=None, imp=None, per_row: bool = False,
                  dppu_src: str = "none", perrow_wf: bool = False,
                  bk: int = 128, bits: int = 8, acc_bits: int = ACC_BITS,
-                 out_bits: int = OUT_BITS, interpret: bool = True):
+                 out_bits: int = OUT_BITS, interpret: bool | None = None):
     """One fused decode step.
 
     Args:
@@ -197,6 +230,6 @@ def fused_decode(xq, wq, oflips, q_scale, *, wq_clean=None, wflips=None,
         out_shape=[jax.ShapeDtypeStruct((M, N), jnp.int8),
                    jax.ShapeDtypeStruct((M, 1), jnp.int32)],
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=resolve_interpret(interpret),
     )(*operands)

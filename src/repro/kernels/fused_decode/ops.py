@@ -14,7 +14,10 @@ backend:
 Because the draws are identical and the integer datapath is deterministic,
 ``fused_protect_linear(key, ...) == _protect_reference(key, ...)`` holds
 bitwise for every registry policy, global or per-row keys, with or without
-weight faults, and with traced ``dyn`` knob overrides.
+weight faults, and with traced ``dyn`` knob overrides.  That contract is
+also what lets a call whose static shapes exceed the kernel's VMEM plan
+(``kernel.vmem_bytes`` over ``kernel.VMEM_LIMIT``: prefill rows, per-row
+weight faults at model widths) run the reference datapath instead.
 """
 from __future__ import annotations
 
@@ -25,7 +28,8 @@ import jax.numpy as jnp
 
 from repro.core import faults
 from repro.core import quantization as Q
-from repro.kernels.fused_decode.kernel import fused_decode
+from repro.kernels.fused_decode.kernel import (VMEM_LIMIT, fused_decode,
+                                               vmem_bytes)
 
 
 def _pad_to(a: jax.Array, mults: tuple[int, ...]) -> jax.Array:
@@ -35,22 +39,46 @@ def _pad_to(a: jax.Array, mults: tuple[int, ...]) -> jax.Array:
     return a
 
 
+def _up(v: int, mult: int) -> int:
+    return -(-v // mult) * mult
+
+
+def kernel_fits(m: int, n: int, *, dppu_src: str = "none",
+                perrow_wf: bool = False) -> bool:
+    """Whether a call with m rows and n output columns runs the kernel: its
+    VMEM plan at the padded shape stays within ``VMEM_LIMIT``."""
+    return vmem_bytes(_up(m, 8), _up(n, 128), dppu_src=dppu_src,
+                      perrow_wf=perrow_wf) <= VMEM_LIMIT
+
+
 @partial(jax.jit, static_argnames=("layer_protected", "interpret"))
 def fused_protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
                          policy, important: jax.Array | None = None, *,
                          layer_protected: bool = True, dyn=None,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """Fault-tolerant linear on the fused kernel: float in/out.
 
     Accepts everything ``protect_linear`` does — a single key or an (M, 2)
     per-row key batch, all registry policies (weight faults included, also
     per-row), ``important`` masks, ``layer_protected`` and traced ``dyn``
-    overrides — and matches the reference backend bit-for-bit.
+    overrides — and matches the reference backend bit-for-bit.  A call the
+    kernel's VMEM plan cannot hold (``kernel_fits``) runs the reference.
     """
     orig_shape = x.shape
     x2 = x.reshape(-1, orig_shape[-1])
     m, n = x2.shape[0], w.shape[1]
     per_row = getattr(key, "ndim", 1) == 2
+    perrow_wf = policy.weight_faults and per_row
+    if policy.arch.recompute and important is not None:
+        dppu_src = ("w" if perrow_wf          # wq operand is clean
+                    else "wcl" if policy.weight_faults  # wq pre-faulted
+                    else "reuse")             # no weight faults: acc reused
+    else:
+        dppu_src = "none"
+    if not kernel_fits(m, n, dppu_src=dppu_src, perrow_wf=perrow_wf):
+        from repro.ft.api import _protect_reference
+        return _protect_reference(key, x, w, policy, important,
+                                  layer_protected, dyn)
     if per_row:
         ks = jax.vmap(lambda k: jax.random.split(k, 3))(key)   # (M, 3, 2)
         kw, ka, kd = ks[:, 0], ks[:, 1], ks[:, 2]
@@ -66,12 +94,11 @@ def fused_protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
     wq, sw = Q.quantize(w)
 
     # weight-fault flip words — same draws as inject_weight_faults
-    wq_k, wq_clean, wflips, perrow_wf = wq, None, None, False
+    wq_k, wq_clean, wflips = wq, None, None
     if policy.weight_faults:
         if per_row:
             wflips = jax.vmap(lambda k: faults.flip_word(
                 k, wq.shape, policy.ber, Q.OUT_BITS))(kw)      # (M, K, N)
-            perrow_wf = True
         else:
             wq_k = faults.inject_weight_faults(kw, wq, policy.ber)
             wq_clean = wq
@@ -89,8 +116,8 @@ def fused_protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
         oflips = faults.flip_word(ka, (m, n), policy.ber, Q.OUT_BITS, pmask)
 
     # DPPU recompute flip words
-    dflips, imp_arr, dppu_src = None, None, "none"
-    if arch.recompute and important is not None:
+    dflips, imp_arr = None, None
+    if dppu_src != "none":
         dmask = faults.protect_mask(
             jnp.broadcast_to(jnp.asarray(ib_th, jnp.int32), (n,)), Q.OUT_BITS)
         if per_row:
@@ -100,12 +127,6 @@ def fused_protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
             dflips = faults.flip_word(kd, (m, n), policy.ber, Q.OUT_BITS,
                                       dmask)
         imp_arr = important.astype(jnp.int32)
-        if perrow_wf:
-            dppu_src = "w"          # wq operand is clean; flips are separate
-        elif wq_clean is not None:
-            dppu_src = "wcl"        # wq operand pre-faulted; recompute clean
-        else:
-            dppu_src = "reuse"      # no weight faults: clean acc == acc
 
     # tile-align (zero pads are exact for the integer datapath; padded rows
     # have absmax 0 so they never move a per-row or global t)

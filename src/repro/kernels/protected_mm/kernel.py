@@ -20,8 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import resolve_interpret
 
 ACC_BITS = 24
 OUT_BITS = 8
@@ -73,7 +72,7 @@ def _kernel(x_ref, w_ref, rnd_o_ref, rnd_i_ref, imp_ref, o_ref, acc_ref, *,
 def protected_mm(xq, wq, rnd_ord, rnd_imp, imp_mask, *, t: int, ber: float,
                  ib: int = 2, nb: int = 1, bits: int = 8,
                  bm: int = 128, bn: int = 128, bk: int = 128,
-                 acc_bits: int = ACC_BITS, interpret: bool = True):
+                 acc_bits: int = ACC_BITS, interpret: bool | None = None):
     """xq (M,K) int8; wq (K,N) int8; rnd_* (bits,M,N) uint32;
     imp_mask (N,) int32 -> (M,N) int8."""
     M, K = xq.shape
@@ -95,7 +94,7 @@ def protected_mm(xq, wq, rnd_ord, rnd_imp, imp_mask, *, t: int, ber: float,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xq, wq, rnd_ord, rnd_imp, imp_mask.reshape(1, N))

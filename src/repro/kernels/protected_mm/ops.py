@@ -27,7 +27,7 @@ def calibrate_t(x, w, q_scale: int = 7) -> int:
 
 @partial(jax.jit, static_argnames=("t", "ber", "ib", "nb", "interpret"))
 def ft_linear_fused(key, x, w, important, *, t: int, ber: float, ib: int = 2,
-                    nb: int = 1, interpret: bool = True):
+                    nb: int = 1, interpret: bool | None = None):
     """x: (M, K) float; w: (K, N) float; important: (N,) bool."""
     xq, sx = Q.quantize(x)
     wq, sw = Q.quantize(w)

@@ -15,8 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import resolve_interpret
 
 ACC_BITS = 24
 OUT_BITS = 8
@@ -45,7 +44,7 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref, *, t: int, nk: int, acc_bits: int):
 @functools.partial(jax.jit, static_argnames=("t", "bm", "bn", "bk",
                                              "acc_bits", "interpret"))
 def qmatmul(xq, wq, t: int, bm: int = 128, bn: int = 128, bk: int = 128,
-            acc_bits: int = ACC_BITS, interpret: bool = True):
+            acc_bits: int = ACC_BITS, interpret: bool | None = None):
     """xq: (M, K) int8; wq: (K, N) int8 -> (M, N) int8."""
     M, K = xq.shape
     _, N = wq.shape
@@ -62,7 +61,7 @@ def qmatmul(xq, wq, t: int, bm: int = 128, bn: int = 128, bk: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xq, wq)

@@ -11,7 +11,7 @@ from repro.kernels.qmatmul.kernel import qmatmul
 
 
 @partial(jax.jit, static_argnames=("t", "interpret"))
-def quant_linear(x, w, t: int, interpret: bool = True):
+def quant_linear(x, w, t: int, interpret: bool | None = None):
     """Float-in/float-out linear through the int8 DLA datapath kernel."""
     xq, sx = Q.quantize(x)
     wq, sw = Q.quantize(w)

@@ -14,8 +14,12 @@ Usage:
   python -m repro.launch.dryrun [--arch A] [--shape S] [--mesh single|multi|both]
 """
 import os
+
+from repro.launch.compile_cache import use_compile_cache
+
 os.environ["XLA_FLAGS"] = (os.environ.get("_REPRO_XLA_EXTRA", "") +
                            " --xla_force_host_platform_device_count=512")
+use_compile_cache()
 
 import argparse      # noqa: E402
 import json          # noqa: E402
@@ -27,7 +31,7 @@ import jax           # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.configs import ARCHS, SHAPES, get_config, get_run_config  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh, make_production_mesh  # noqa: E402
 from repro.models import build  # noqa: E402
 from repro.optim import AdamWConfig  # noqa: E402
 from repro.parallel import sharding as S  # noqa: E402
@@ -306,10 +310,9 @@ def main():
 
     meshes = []
     if args.tp:
-        import jax as _jax
         meshes.append((f"single_tp{args.tp}",
-                       _jax.make_mesh((256 // args.tp, args.tp),
-                                      ("data", "model"))))
+                       make_mesh((256 // args.tp, args.tp),
+                                 ("data", "model"))))
     else:
         if args.mesh in ("single", "both"):
             meshes.append(("single", make_production_mesh(multi_pod=False)))

@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import argparse
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -25,6 +27,7 @@ def main():
                     help="call jax.distributed.initialize() (TPU fleet)")
     args = ap.parse_args()
 
+    use_compile_cache()
     import jax
     if args.distributed:
         jax.distributed.initialize()

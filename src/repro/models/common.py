@@ -88,8 +88,8 @@ class FTCtx:
     "reference" (functional model) or "pallas" (fused TPU kernel).  The
     pallas kernel takes the truncation LSB statically, so under jit supply
     ``t`` — one int for all sites or a per-site {name: int} calibration
-    table (repro.ft.calibrate_t) — and ``interpret=False`` to run the
-    compiled kernel on TPU.
+    table (repro.ft.calibrate_t).  Kernels run compiled on the chip and
+    interpreted on the CPU backend (``repro.kernels.resolve_interpret``).
 
     ``dyn`` optionally carries traced overrides of the policy's numeric
     protection knobs ({"ib_th": ..., "nb_th": ..., "q_scale": ...}) so a
@@ -109,8 +109,8 @@ class FTCtx:
     docs/training.md)."""
 
     def __init__(self, ft, key, masks=None, protected_layers=None,
-                 backend: str = "reference", t=None, interpret: bool = True,
-                 dyn=None, ste: bool = False):
+                 backend: str = "reference", t=None, dyn=None,
+                 ste: bool = False):
         from repro.ft import as_policy
         self.ft = as_policy(ft)
         self.key = key
@@ -118,7 +118,6 @@ class FTCtx:
         self.protected_layers = protected_layers  # set of layer names (arch/alg)
         self.backend = backend
         self.t = t
-        self.interpret = interpret
         self.dyn = dyn
         self.ste = ste
 
@@ -172,8 +171,7 @@ def linear(x: jax.Array, w: jax.Array, b=None, *,
                w2, ftc.ft,
                important=None if imp is None else jnp.asarray(imp),
                layer_protected=prot, backend=ftc.backend,
-               t=ftc.site_t(name), interpret=ftc.interpret,
-               dyn=ftc.dyn)
+               t=ftc.site_t(name), dyn=ftc.dyn)
         y = y.reshape(*x.shape[:-1], *w.shape[1:]).astype(x.dtype)
     if b is not None:
         y = y + b.astype(y.dtype)

@@ -30,7 +30,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.common import activation, dense_init, linear
 from repro.parallel import ctx as pctx
-from repro.parallel.compat import shard_map
 
 
 def init(key, cfg, dtype):
@@ -150,11 +149,11 @@ def apply(p, x, cfg, probe=None, ftc=None, name="moe"):
             # operands: bit-identical to the single-shard path by
             # construction (tests/test_serve_sharded.py, MoE scheduler arm).
             wg_arg = jnp.zeros((), x.dtype) if wg is None else wg
-            y, lb = shard_map(
+            y, lb = jax.shard_map(
                 lambda xs, lg, wi, wg_, wo: _local_moe(
                     xs, lg, wi, None if wg is None else wg_, wo, **one),
                 mesh=ctx.mesh, in_specs=(P(), P(), P(), P(), P()),
-                out_specs=(P(), P()), check=False)(
+                out_specs=(P(), P()), check_vma=False)(
                     x, logits, p["wi"], wg_arg, p["wo"])
         return y, cfg.moe.aux_coef * lb.mean()
 
@@ -177,9 +176,9 @@ def apply(p, x, cfg, probe=None, ftc=None, name="moe"):
         wg_arg = jnp.zeros((), x.dtype)
     else:
         wg_arg = wg
-    y, lb = shard_map(
+    y, lb = jax.shard_map(
         lambda xs, lg, wi, wg_, wo: shard_fn(
             xs, lg, wi, None if wg is None else wg_, wo),
         mesh=ctx.mesh, in_specs=in_specs, out_specs=out_specs,
-        check=False)(x, logits, p["wi"], wg_arg, p["wo"])
+        check_vma=False)(x, logits, p["wi"], wg_arg, p["wo"])
     return y, cfg.moe.aux_coef * lb.mean()

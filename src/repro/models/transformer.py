@@ -200,7 +200,7 @@ def backbone(params, x, *, cfg, run, mode="train", caches=None,
 
     # scanned super-block segments
     new_caches: dict | None = None
-    for si, (pattern, _n) in enumerate(cfg.segments):
+    for si, (pattern, n_rep) in enumerate(cfg.segments):
         def sb(carry, inp, pattern=pattern):
             x, aux = carry
             # sequence-parallel residual boundary: the per-block saved
@@ -209,8 +209,7 @@ def backbone(params, x, *, cfg, run, mode="train", caches=None,
             # residual memory, and the TP all-reduce decomposes into
             # all-gather + reduce-scatter at identical wire cost (Megatron-SP)
             x = ac(x, "dp", "tp", None)
-            blk_p = inp[0]
-            blk_c = inp[1] if len(inp) > 1 else None
+            blk_p, blk_c = inp
             new_c = {}
             for j, kind in enumerate(pattern):
                 c = None if blk_c is None else blk_c.get(f"s{j}")
@@ -226,9 +225,29 @@ def backbone(params, x, *, cfg, run, mode="train", caches=None,
         body = sb
         if run.remat == "block":
             body = jax.checkpoint(sb, prevent_cse=False)
-        xs = ((params[f"seg{si}"],) if caches is None else
-              (params[f"seg{si}"], caches[f"seg{si}"]))
-        (x, aux_total), seg_caches = jax.lax.scan(body, (x, aux_total), xs)
+        if caches is not None:
+            # decode carries the stacked caches through the layer scan and
+            # writes each layer's slice back in place: emitted as scan
+            # outputs they would be a fresh copy of the whole KV cache per
+            # step, which a decode loop's while-carry multiplies (~5 copies
+            # at full width — more than a 16 GB chip holds)
+            def dec(carry, inp, sb=sb):
+                x, aux, cs = carry
+                blk_p, i = inp
+                (x, aux), nc = sb((x, aux),
+                                  (blk_p, jax.tree.map(lambda c: c[i], cs)))
+                cs = jax.tree.map(
+                    lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                        c, n, i, 0), cs, nc)
+                return (x, aux, cs), None
+
+            (x, aux_total, seg_caches), _ = jax.lax.scan(
+                dec, (x, aux_total, caches[f"seg{si}"]),
+                (params[f"seg{si}"], jnp.arange(n_rep)))
+        else:
+            (x, aux_total), seg_caches = jax.lax.scan(
+                lambda c, p: body(c, (p, None)), (x, aux_total),
+                params[f"seg{si}"])
         if seg_caches is not None:
             new_caches = dict(new_caches or {})
             new_caches[f"seg{si}"] = seg_caches

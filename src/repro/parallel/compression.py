@@ -11,9 +11,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-
-from repro.parallel.compat import shard_map
+import numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def quantize_grad(g, ef=None):
@@ -38,15 +37,15 @@ def compressed_psum(g, axis, ef=None):
 
 def compressed_psum_test(key, n_dev: int = 8) -> float:
     """Relative error of one compressed mean-reduce vs exact (test helper)."""
-    mesh = jax.make_mesh((n_dev,), ("d",))
+    mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("d",))
     g = jax.random.normal(key, (n_dev, 64, 64))
 
     def shard_fn(gl):
         out, _ = compressed_psum(gl[0], "d")
         return out[None]
 
-    out = jax.jit(shard_map(shard_fn, mesh=mesh, in_specs=P("d"),
-                            out_specs=P("d")))(g)
+    out = jax.jit(jax.shard_map(shard_fn, mesh=mesh, in_specs=P("d"),
+                                out_specs=P("d")))(g)
     exact = g.mean(0)
     err = float(jnp.linalg.norm(out[0] - exact) / jnp.linalg.norm(exact))
     return err

@@ -59,12 +59,12 @@ class ServeStats:
 class Engine:
     def __init__(self, model, params, mesh=None, cfg: ServeConfig | None = None,
                  policy=None, ft_backend: str = "reference", ft_t=None,
-                 ft_interpret: bool = True, loop: str | None = None):
+                 loop: str | None = None):
         """`policy`: a repro.ft ProtectionPolicy (or registry name) applied to
         every projection.  For ft_backend="pallas" under the jitted serve
         loop, `ft_t` must carry the calibrated truncation LSB(s) — one int or
-        a per-site {name: int} table — and ft_interpret=False runs the
-        compiled kernel on TPU.  `loop` overrides cfg.loop."""
+        a per-site {name: int} table.  Kernels run compiled on the chip and
+        interpreted on the CPU backend.  `loop` overrides cfg.loop."""
         from repro.ft import as_policy
         self.model, self.params = model, params
         self.mesh = mesh
@@ -75,7 +75,6 @@ class Engine:
         self.policy = as_policy(policy)
         self.ft_backend = ft_backend
         self.ft_t = ft_t
-        self.ft_interpret = ft_interpret
         self.stats = ServeStats()
         self._n_calls = 0
         ctx = S.make_ctx(mesh) if mesh is not None else None
@@ -96,7 +95,7 @@ class Engine:
                 return None
             from repro.models.common import FTCtx
             return FTCtx(self.policy, ftkey, backend=self.ft_backend,
-                         t=self.ft_t, interpret=self.ft_interpret)
+                         t=self.ft_t)
 
         temperature = self.cfg.temperature
 

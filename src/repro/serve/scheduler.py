@@ -120,7 +120,7 @@ class SchedStats:
 class Scheduler:
     def __init__(self, model, params, cfg: SchedulerConfig | None = None,
                  policy=None, ft_backend: str = "reference", ft_t=None,
-                 ft_interpret: bool = True, mesh=None):
+                 mesh=None):
         """``mesh``: a jax Mesh — params are device_put in the serving layout
         (TP over 'model', DP-replicated), the slot caches are sharded per
         ``parallel.sharding.cache_shardings`` (batch over DP, heads over
@@ -210,8 +210,7 @@ class Scheduler:
             if self.policy is None:
                 return None
             from repro.models.common import FTCtx
-            return FTCtx(self.policy, keys, backend=ft_backend, t=ft_t,
-                         interpret=ft_interpret)
+            return FTCtx(self.policy, keys, backend=ft_backend, t=ft_t)
 
         def _sample(logits, keys, tsteps):
             if temperature <= 0:

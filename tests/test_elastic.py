@@ -67,6 +67,7 @@ def test_elastic_closed_loop_matches_uninterrupted(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config
         from repro.configs.base import RunConfig, ShapeConfig
+        from repro.launch.mesh import make_mesh
         from repro.models import build
         from repro.optim import AdamWConfig
         from repro.train import Trainer, TrainerConfig
@@ -79,7 +80,7 @@ def test_elastic_closed_loop_matches_uninterrupted(tmp_path):
                                         compute_dtype='float32'))
         shape = ShapeConfig('tiny', 'train', 64, 8)
         opt = AdamWConfig(lr=1e-3)
-        mesh2 = jax.make_mesh((2, 1), ('data', 'model'))
+        mesh2 = make_mesh((2, 1), ('data', 'model'))
 
         # uninterrupted reference: 8 steps on the 2-device mesh
         tc_ref = TrainerConfig(total_steps=8, ckpt_every=100, log_every=1000,

@@ -258,7 +258,7 @@ def test_ftl005_negative_full_kernel_contract():
             kernel,
             out_shape=x,
             interpret=interpret,
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
         )(x)
@@ -275,7 +275,7 @@ def test_ftl005_positive_hardcoded_interpret():
         assert x.shape[0] % bm == 0
         return pl.pallas_call(
             kernel, out_shape=x, interpret=True,
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
         )(x)
     """

@@ -65,7 +65,7 @@ def test_ftv101_flags_narrow_integer_accumulation():
     def bad(x, w):
         acc = jax.lax.dot_general(x, w, _DN,
                                   preferred_element_type=jnp.int16)
-        return jax.lax.shift_right_arithmetic(acc, 2)
+        return jax.lax.shift_right_arithmetic(acc, jnp.int16(2))
 
     g = graph_of(bad, _sds((4, 8), jnp.int16), _sds((8, 8), jnp.int16))
     out = check_backward_slices(g, fnd)

@@ -99,3 +99,39 @@ def test_full_configs_construct_specs_only():
         assert 0.65 * exp < n_params < 1.35 * exp, (arch, n_params, exp)
         bs = m.batch_specs(SHAPES["train_4k"])
         assert bs["tokens"].shape[0] == 256
+
+
+def _stack_unrolled(params, cfg):
+    """The unrolled model's per-layer params, stacked into the scanned
+    model's segment layout (layer b*len(pattern)+j -> seg0/s{j}[b])."""
+    out = {k: v for k, v in params.items() if k != "layers"}
+    (pattern, n_rep), = cfg.segments
+    out["seg0"] = {
+        f"s{j}": jax.tree.map(
+            lambda *ls: jnp.stack(ls),
+            *[params["layers"][f"l{b * len(pattern) + j}"]
+              for b in range(n_rep)])
+        for j in range(len(pattern))}
+    return out
+
+
+@pytest.mark.parametrize("arch", ["h2o-danube-1.8b", "gemma2-27b"])
+def test_scanned_decode_serves_like_unrolled(arch):
+    """Scanned decode carries the stacked KV caches through the layer scan
+    and updates them in place; the served tokens match the python-loop
+    layers on the same weights."""
+    from repro.serve.scheduler import Request, Scheduler, SchedulerConfig
+    cfg_u = get_config(arch, reduced=True)
+    cfg_s = dataclasses.replace(cfg_u, unroll=False)
+    pu = build(cfg_u).init(jax.random.PRNGKey(0))
+    scfg = SchedulerConfig(max_batch=2, buckets=(8, 16), max_new_tokens=6,
+                           decode_chunk=2)
+    rng = np.random.default_rng(0)
+    reqs = [(i, rng.integers(0, cfg_u.vocab, n).tolist())
+            for i, n in enumerate((5, 12, 8))]
+    out = []
+    for cfg, params in ((cfg_u, pu), (cfg_s, _stack_unrolled(pu, cfg_s))):
+        sched = Scheduler(build(cfg), params, scfg)
+        res = sched.run([Request(r, t, max_new_tokens=6) for r, t in reqs])
+        out.append({r: res[r].generated for r, _ in reqs})
+    assert out[0] == out[1]

@@ -28,6 +28,7 @@ def test_sharded_train_step_matches_single_device():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config
         from repro.configs.base import RunConfig
+        from repro.launch.mesh import make_mesh
         from repro.models import build
         from repro.optim import AdamWConfig
         from repro.train import init_state, make_train_step
@@ -41,7 +42,7 @@ def test_sharded_train_step_matches_single_device():
         s0 = init_state(m, jax.random.PRNGKey(0), opt)
         _, st_local = make_train_step(m, opt, mesh=None)
         s1, met1 = st_local(jax.tree.map(jnp.copy, s0), batch)
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         _, st_mesh = make_train_step(m, opt, mesh=mesh)
         s2, met2 = st_mesh(jax.tree.map(jnp.copy, s0), batch)
         d = max(float(jnp.abs(a - b).max()) for a, b in
@@ -61,6 +62,7 @@ def test_moe_shard_map_matches_single_device():
         import jax, jax.numpy as jnp, dataclasses
         from repro.configs import get_config
         from repro.configs.base import RunConfig
+        from repro.launch.mesh import make_mesh
         from repro.models import build
         from repro.parallel import sharding as S
         from repro.parallel.ctx import mesh_ctx
@@ -75,7 +77,7 @@ def test_moe_shard_map_matches_single_device():
         batch = {'tokens': jax.random.randint(jax.random.PRNGKey(1), (8, 16),
                                               0, cfg.vocab)}
         l0, _ = jax.jit(m.loss)(params, batch)     # single-device path
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         ctx = S.make_ctx(mesh)
         def loss_mesh(p, b):
             with mesh_ctx(ctx):
@@ -94,6 +96,7 @@ def test_elastic_remesh_restore(tmp_path):
         import jax, jax.numpy as jnp, numpy as np, dataclasses
         from repro.configs import get_config
         from repro.configs.base import RunConfig, ShapeConfig
+        from repro.launch.mesh import make_mesh
         from repro.models import build
         from repro.optim import AdamWConfig
         from repro.train import init_state, make_train_step, state_shardings
@@ -105,7 +108,7 @@ def test_elastic_remesh_restore(tmp_path):
         opt = AdamWConfig(lr=1e-3)
         batch = {{'tokens': jax.random.randint(jax.random.PRNGKey(1), (8, 64),
                                                0, cfg.vocab)}}
-        mesh8 = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh8 = make_mesh((4, 2), ('data', 'model'))
         _, step8 = make_train_step(m, opt, mesh=mesh8)
         s = init_state(m, jax.random.PRNGKey(0), opt)
         s, _ = step8(s, batch)
@@ -113,7 +116,7 @@ def test_elastic_remesh_restore(tmp_path):
         # "lose" half the data hosts: 8 -> 4 devices
         plan = plan_rescale(mesh8, surviving_devices=4, model_axis=2)
         assert plan.new_dp == 2 and plan.grad_accum_scale == 2
-        mesh4 = jax.make_mesh((2, 2), ('data', 'model'))
+        mesh4 = make_mesh((2, 2), ('data', 'model'))
         like = jax.eval_shape(lambda k: init_state(m, k, opt),
                               jax.random.PRNGKey(0))
         s4, step, _, ctx = remesh_restore('{tmp_path}/ck', like, mesh4)

@@ -58,7 +58,7 @@ def test_scan_matches_python_pallas_backend(danube):
     m, params, batch = danube
     policy = _policy("crt3")
     scan, py = _pair(m, params, n_new=4, policy=policy, ft_backend="pallas",
-                     ft_t=6, ft_interpret=True)
+                     ft_t=6)
     a = np.asarray(scan.generate(batch, seed=3))
     b = np.asarray(py.generate(batch, seed=3))
     np.testing.assert_array_equal(a, b)

@@ -150,7 +150,6 @@ def test_fold_axis_index_shard_map_contract():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core.faults import fold_axis_index, fold_stream
-    from repro.parallel.compat import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ('i',))
     base = jax.random.PRNGKey(42)
@@ -159,8 +158,8 @@ def test_fold_axis_index_shard_map_contract():
         k = fold_axis_index(base, 'i')
         return jax.random.uniform(k, (1, 4))
 
-    y = shard_map(f, mesh=mesh, in_specs=(P('i'),), out_specs=P('i'),
-                  check=False)(jnp.zeros((8,)))
+    y = jax.shard_map(f, mesh=mesh, in_specs=(P('i'),), out_specs=P('i'),
+                      check_vma=False)(jnp.zeros((8,)))
     ref = np.stack([np.asarray(jax.random.uniform(fold_stream(base, s), (4,)))
                     for s in range(8)])
     assert (np.asarray(y) == ref).all()

@@ -6,15 +6,8 @@ from jax.tree_util import DictKey
 
 from repro.parallel import sharding as S
 
-def _abstract_mesh(sizes, names):
-    try:  # jax >= 0.5: AbstractMesh(axis_sizes, axis_names)
-        return AbstractMesh(sizes, names)
-    except TypeError:  # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH_MP = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def spec_of(names, shape, mesh=MESH):

@@ -1,6 +1,6 @@
 """Jaxpr dataflow graph for ftverify.
 
-``build_graph`` flattens a ``ClosedJaxpr`` — descending into ``pjit`` /
+``build_graph`` flattens a ``ClosedJaxpr`` — descending into ``jit`` /
 ``scan`` / ``while`` / ``cond`` / ``custom_*`` / ``pallas_call`` sub-jaxprs —
 into one global def-use graph.  Sub-jaxpr binders are *aliased* to their
 call-site operands with a union-find, so a backward walk from a truncation
@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 # jaxpr types (jax 0.4.x public-ish surface)
-from jax.core import ClosedJaxpr, Jaxpr, Literal  # noqa: F401
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal  # noqa: F401
 
 RNG_PRIMS = frozenset({
     "random_bits", "random_fold_in", "random_split", "random_wrap",
@@ -45,7 +45,7 @@ PASSTHROUGH_PRIMS = frozenset({
 # call-like primitives whose outputs alias a sub-jaxpr's outputs; concrete
 # inner eqns take precedence over these in the producer map (see _finish)
 CALL_LIKE_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "remat2", "checkpoint",
+    "jit", "closed_call", "core_call", "remat2", "checkpoint",
     "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
     "scan", "while", "cond", "pallas_call",
 })
@@ -59,7 +59,7 @@ class GEqn:
     invars: list[int]            # global var ids (literals get fresh ids)
     outvars: list[int]
     eqn: Any                     # the JaxprEqn (params via eqn.params)
-    path: tuple[str, ...]        # lexical nesting, e.g. ("pjit", "scan")
+    path: tuple[str, ...]        # lexical nesting, e.g. ("jit", "scan")
     scans: tuple[int, ...]       # idx of each enclosing scan GEqn
 
 
@@ -253,7 +253,7 @@ def _enter(g: Graph, closed: ClosedJaxpr, env: dict[int, int]) -> tuple:
 def _descend(g: Graph, node: GEqn, path: tuple[str, ...],
              scans: tuple[int, ...]) -> None:
     # Every descent opens a FRESH binding scope: jax dedupes traced
-    # sub-jaxprs, so two pjit eqns (e.g. two bernoulli calls) can share one
+    # sub-jaxprs, so two jit eqns (e.g. two bernoulli calls) can share one
     # inner Jaxpr *object* — binding its vars in a shared env would union
     # both call sites' operands onto one binder and merge unrelated values.
     prim, params = node.prim, node.eqn.params
@@ -310,7 +310,7 @@ def _descend(g: Graph, node: GEqn, path: tuple[str, ...],
                 g.union(a, b)
         return
 
-    # generic call-like primitives: pjit, closed_call, remat2, custom_*
+    # generic call-like primitives: jit, closed_call, remat2, custom_*
     closed = _sub_closed(params, "jaxpr", "call_jaxpr", "fun_jaxpr")
     if closed is None:
         return
@@ -318,7 +318,7 @@ def _descend(g: Graph, node: GEqn, path: tuple[str, ...],
     sub = _enter(g, closed, senv2)
     in_ids = [_bind(g, senv2, v) for v in sub.invars]
     # Alias binders to call-site operands only on an exact arity match (true
-    # for pjit/closed_call; custom_vjp-style prims with implicit extras get
+    # for jit/closed_call; custom_vjp-style prims with implicit extras get
     # no aliasing — walks stop at the boundary, a conservative miss, rather
     # than risking wrong unions that chain-merge unrelated values).
     if len(in_ids) == len(node.invars):

@@ -33,7 +33,7 @@ QUANT_OK = frozenset({
     "concatenate", "expand_dims", "rev", "copy", "stop_gradient",
 })
 CALL_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "remat2", "checkpoint",
+    "jit", "closed_call", "core_call", "remat2", "checkpoint",
     "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
     "scan", "while", "cond", "pallas_call",
 })
@@ -154,7 +154,7 @@ def _reenters_int_without_round(g, start, depth: int = 8) -> bool:
                 if not g.is_float(ov):
                     continue
                 # ce may be a call eqn wrapping the round (jnp.round is a
-                # pjit); the producer map prefers inner eqns, so a rounded
+                # jit); the producer map prefers inner eqns, so a rounded
                 # output identifies itself here
                 pr = g.producer(ov)
                 if pr is not None and pr[0].prim == "round":

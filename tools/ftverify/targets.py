@@ -73,8 +73,7 @@ def _protect_targets() -> list[Target]:
 
     def fused():
         return jax.make_jaxpr(
-            lambda k, xx, ww: fused_protect_linear(k, xx, ww, pol,
-                                                   interpret=True))(
+            lambda k, xx, ww: fused_protect_linear(k, xx, ww, pol))(
                 _key_aval(), x, w)
 
     def perrow():
