@@ -21,8 +21,6 @@ def _benchmarks():
     from benchmarks import paper_figs as F
     from benchmarks import roofline as R
     from benchmarks.dse_batch import dse_batched_vs_sequential
-    from benchmarks.fused_bench import fused_vs_composed
-    from benchmarks.serve_bench import serve_scaling, serve_scan_vs_python
     from benchmarks.train_bench import fat_dse, fat_vs_baseline
 
     def roofline_single():
@@ -46,9 +44,6 @@ def _benchmarks():
         "fig14_bit_area": F.fig14_bit_area,
         "fig15_table2_dse": F.fig15_table2_dse,
         "dse_batched_vs_sequential": dse_batched_vs_sequential,
-        "fused_vs_composed": fused_vs_composed,
-        "serve_scan_vs_python": serve_scan_vs_python,
-        "serve_scaling": serve_scaling,
         "fat_vs_baseline": fat_vs_baseline,
         "fat_dse": fat_dse,
         "roofline_single_pod": roofline_single,
@@ -57,10 +52,8 @@ def _benchmarks():
 
 
 # DSE entries rerun fault injection many times; the batched-vs-sequential
-# comparison deliberately includes a slow sequential arm.  serve_scaling
-# compiles one sharded engine per (config, policy, device-count) arm.
-FAST_SKIP = {"fig15_table2_dse", "dse_batched_vs_sequential", "fat_dse",
-             "serve_scaling"}
+# comparison deliberately includes a slow sequential arm.
+FAST_SKIP = {"fig15_table2_dse", "dse_batched_vs_sequential", "fat_dse"}
 
 
 def main() -> None:
@@ -74,18 +67,6 @@ def main() -> None:
         benches = {k: v for k, v in benches.items() if args.only in k}
     if args.fast:
         benches = {k: v for k, v in benches.items() if k not in FAST_SKIP}
-    if "serve_scaling" in benches:
-        if len(benches) > 1:
-            # its host devices and excess-precision pin would change every
-            # other entry's backend: it runs only when selected alone
-            del benches["serve_scaling"]
-            print("# serve_scaling skipped: run it alone "
-                  "(--only serve_scaling)")
-        else:
-            # its 1/2/4-device arms run in this process: the CPU backend
-            # needs its host devices before JAX starts it
-            from benchmarks.serve_bench import pin_scaling_flags
-            pin_scaling_flags()
     out = {}
     print("name,us_per_call,derived")
     for name, fn in benches.items():

@@ -49,7 +49,7 @@ import numpy as np  # noqa: E402
 ARCH = "h2o-danube-1.8b"
 N_REQUESTS, NEW_TOKENS = 16, 32
 PROMPT_LENS = (32, 480)
-BER = 1e-3                           # crt3 arms of benchmarks/serve_bench.py
+BER = 1e-3                           # the benchmark's crt3 configuration
 ALONE = (0, 7)                       # requests re-served alone under crt3
 
 

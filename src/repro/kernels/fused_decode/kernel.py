@@ -232,4 +232,5 @@ def fused_decode(xq, wq, oflips, q_scale, *, wq_clean=None, wflips=None,
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=resolve_interpret(interpret),
+        name="fused_decode",
     )(*operands)

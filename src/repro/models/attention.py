@@ -144,20 +144,39 @@ def apply(p, x, *, cfg, run, kind, positions, probe=None, ftc=None,
     modes: train (no cache) | prefill (build cache) | decode (1-token step).
     enc_kv: (k, v) from the encoder for cross-attention (positions=None keys).
     """
-    B = x.shape[0]
-    D, H, KH, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    H, Dh = cfg.n_heads, cfg.d_head
     window = cfg.window if kind == "L" else 0
     cross = enc_kv is not None
 
     q = linear(x, p["wq"], p.get("bq"), ftc=ftc, name=f"{name}/wq")
-    q = q.reshape(*x.shape[:-1], H, Dh)
     if cross:
         k, v = enc_kv
     else:
         k = linear(x, p["wk"], p.get("bk"), ftc=ftc, name=f"{name}/wk")
         v = linear(x, p["wv"], p.get("bv"), ftc=ftc, name=f"{name}/wv")
-        k = k.reshape(*x.shape[:-1], KH, Dh)
-        v = v.reshape(*x.shape[:-1], KH, Dh)
+    # between the projections and wo: rope, the cache write, the paged
+    # gather and the softmax, under one named scope
+    with jax.named_scope("attention"):
+        o, new_cache = _attend(q, k, v, cfg=cfg, run=run, window=window,
+                               positions=positions, cross=cross, cache=cache,
+                               mode=mode, dtype=x.dtype)
+        o = tag(probe, f"{name}/out", o)
+    y = linear(o.reshape(*x.shape[:-1], H * Dh), p["wo"], ftc=ftc,
+               name=f"{name}/wo")
+    return y, new_cache
+
+
+def _attend(q, k, v, *, cfg, run, window, positions, cross, cache, mode,
+            dtype):
+    """Attention from projected q/k/v: (o (B, S, H, Dh), new cache); q is
+    scaled in ``dtype``, the sub-layer input's."""
+    B = q.shape[0]
+    H, KH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    lead = q.shape[:-1]
+    q = q.reshape(*lead, H, Dh)
+    if not cross:
+        k = k.reshape(*lead, KH, Dh)
+        v = v.reshape(*lead, KH, Dh)
         k = rope(k, positions, cfg.rope_theta)
         # head-shard k/v like q: without this the residual stream's
         # sequence sharding propagates into the kv length dim, turning the
@@ -169,7 +188,7 @@ def apply(p, x, *, cfg, run, kind, positions, probe=None, ftc=None,
         v = ac(v, "dp", None, "tp", None)
     if not cross:
         q = rope(q, positions, cfg.rope_theta)
-    q = (q * _scale(cfg)).astype(x.dtype)
+    q = (q * _scale(cfg)).astype(dtype)
     q = ac(q, "dp", None, "tp", None)
 
     new_cache = cache
@@ -226,11 +245,7 @@ def apply(p, x, *, cfg, run, kind, positions, probe=None, ftc=None,
                               differentiable=(mode == "train"))
         if mode == "prefill" and not cross:
             new_cache = _build_cache(k, v, window)
-    o = ac(o, "dp", None, "tp", None)
-    o = tag(probe, f"{name}/out", o)
-    y = linear(o.reshape(*x.shape[:-1], H * Dh), p["wo"], ftc=ftc,
-               name=f"{name}/wo")
-    return y, new_cache
+    return ac(o, "dp", None, "tp", None), new_cache
 
 
 def _decode_attn(q, kc, vc, n_valid, cap=0.0):

@@ -135,7 +135,14 @@ class FTCtx:
 def linear(x: jax.Array, w: jax.Array, b=None, *,
            ftc: FTCtx | None = None, name: str = "") -> jax.Array:
     """Every projection in the zoo routes through here — the integration point
-    of the paper's technique (ft_linear) with the LM stack."""
+    of the paper's technique (ft_linear) with the LM stack.  Its ops carry
+    the ``linear`` named scope (the protected datapath ``linear/protect``),
+    which a profiler trace's HLO keeps."""
+    with jax.named_scope("linear"):
+        return _linear(x, w, b, ftc=ftc, name=name)
+
+
+def _linear(x, w, b, *, ftc, name):
     if isinstance(ftc, EmuCtx):
         w2 = w.reshape(w.shape[0], -1)
         y = x @ w2
@@ -166,12 +173,13 @@ def linear(x: jax.Array, w: jax.Array, b=None, *,
             reps = max(x.size // x.shape[-1], 1) // sk.shape[0]
             if reps != 1:
                 sk = jnp.repeat(sk, reps, axis=0)
-        y = pl(sk,
-               x.astype(jnp.float32).reshape(-1, w.shape[0]),
-               w2, ftc.ft,
-               important=None if imp is None else jnp.asarray(imp),
-               layer_protected=prot, backend=ftc.backend,
-               t=ftc.site_t(name), dyn=ftc.dyn)
+        with jax.named_scope("protect"):
+            y = pl(sk,
+                   x.astype(jnp.float32).reshape(-1, w.shape[0]),
+                   w2, ftc.ft,
+                   important=None if imp is None else jnp.asarray(imp),
+                   layer_protected=prot, backend=ftc.backend,
+                   t=ftc.site_t(name), dyn=ftc.dyn)
         y = y.reshape(*x.shape[:-1], *w.shape[1:]).astype(x.dtype)
     if b is not None:
         y = y + b.astype(y.dtype)

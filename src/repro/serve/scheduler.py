@@ -63,7 +63,9 @@ therefore every temp-0 token — bit-identical to the 1-device run.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import time
 from functools import partial
 
 import jax
@@ -102,6 +104,12 @@ class SchedulerConfig:
     #                                  default: full provisioning)
 
 
+# host phases of ``Scheduler.run``, each a profiler span and a counter;
+# prefill, first_token and insert nest in admit
+PHASES = ("serve.admit", "serve.prefill", "serve.first_token", "serve.insert",
+          "serve.chunk", "serve.readback", "serve.harvest", "serve.retire")
+
+
 @dataclasses.dataclass
 class SchedStats:
     prefill_calls: int = 0
@@ -110,11 +118,27 @@ class SchedStats:
     retire_calls: int = 0
     tokens: int = 0
     blocks_in_use_peak: int = 0
+    readbacks: int = 0               # device-to-host copies
+    # per phase: host seconds in its spans, and its longest single span
+    host_s: dict = dataclasses.field(default_factory=dict)
+    host_max_s: dict = dataclasses.field(default_factory=dict)
 
     @property
     def roundtrips(self) -> int:
         return (self.prefill_calls + self.insert_calls + self.chunk_calls
                 + self.retire_calls)
+
+    @contextlib.contextmanager
+    def phase(self, name: str, **args):
+        """Time one host phase into ``host_s`` / ``host_max_s`` and mark it
+        as a ``jax.profiler`` span (``args`` become the span's stats), on
+        the profiler's clock when a trace is being taken."""
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(name, **args):
+            yield
+        dt = time.perf_counter() - t0
+        self.host_s[name] = self.host_s.get(name, 0.0) + dt
+        self.host_max_s[name] = max(self.host_max_s.get(name, 0.0), dt)
 
 
 class Scheduler:
@@ -479,6 +503,8 @@ class Scheduler:
             slots[s] = None
             release(s)
 
+        phase = self.stats.phase
+
         while queue or any(s is not None for s in slots):
             # ---- admit into free slots (a request that finishes at
             # prefill — EOS first token or max_new_tokens == 1 — does not
@@ -493,37 +519,41 @@ class Scheduler:
                     if need > len(free_blocks):
                         break               # wait for evictions to free blocks
                     queue.popleft()
-                    batch1, last_idx, plen = self._make_batch1(req)
-                    c1, tok0 = self._prefill_one(
-                        self.params, batch1, last_idx,
-                        jnp.asarray(req.rid, jnp.int32))
-                    self.stats.prefill_calls += 1
-                    t0 = int(tok0)
-                    req.generated.append(t0)
-                    self.stats.tokens += 1
-                    if cfg.eos_id >= 0 and t0 == cfg.eos_id:
-                        req.finish_reason = "eos"
-                        out[req.rid] = req
-                        continue
-                    if len(req.generated) >= req.max_new_tokens:
-                        req.finish_reason = "length"
-                        out[req.rid] = req
-                        continue
-                    if cfg.kv == "paged":
-                        got, bt_g, bt_l = alloc_tables(plen,
-                                                       req.max_new_tokens)
-                        slot_blocks[s] = got
-                        in_use = self.n_blocks - 1 - len(free_blocks)
-                        self.stats.blocks_in_use_peak = max(
-                            self.stats.blocks_in_use_peak, in_use)
-                    else:
-                        bt_g = jnp.zeros((self._wg,), jnp.int32)
-                        bt_l = jnp.zeros((max(self._wl, 1),), jnp.int32)
-                    caches = self._insert(caches, c1,
-                                          jnp.asarray(s, jnp.int32),
-                                          jnp.asarray(plen, jnp.int32),
-                                          bt_g, bt_l)
-                    self.stats.insert_calls += 1
+                    with phase("serve.admit", rid=req.rid):
+                        batch1, last_idx, plen = self._make_batch1(req)
+                        with phase("serve.prefill", rid=req.rid):
+                            c1, tok0 = self._prefill_one(
+                                self.params, batch1, last_idx,
+                                jnp.asarray(req.rid, jnp.int32))
+                        self.stats.prefill_calls += 1
+                        with phase("serve.first_token", rid=req.rid):
+                            t0 = int(tok0)
+                        self.stats.readbacks += 1
+                        req.generated.append(t0)
+                        self.stats.tokens += 1
+                        if cfg.eos_id >= 0 and t0 == cfg.eos_id:
+                            req.finish_reason = "eos"
+                            out[req.rid] = req
+                            continue
+                        if len(req.generated) >= req.max_new_tokens:
+                            req.finish_reason = "length"
+                            out[req.rid] = req
+                            continue
+                        if cfg.kv == "paged":
+                            got, bt_g, bt_l = alloc_tables(
+                                plen, req.max_new_tokens)
+                            slot_blocks[s] = got
+                            in_use = self.n_blocks - 1 - len(free_blocks)
+                            self.stats.blocks_in_use_peak = max(
+                                self.stats.blocks_in_use_peak, in_use)
+                        else:
+                            bt_g = jnp.zeros((self._wg,), jnp.int32)
+                            bt_l = jnp.zeros((max(self._wl, 1),), jnp.int32)
+                        with phase("serve.insert", rid=req.rid):
+                            caches = self._insert(
+                                caches, c1, jnp.asarray(s, jnp.int32),
+                                jnp.asarray(plen, jnp.int32), bt_g, bt_l)
+                        self.stats.insert_calls += 1
                     slots[s] = req
                     admitted += 1
                     tok[s], pos[s], tstep[s], rids[s] = t0, plen, 0, req.rid
@@ -538,37 +568,42 @@ class Scheduler:
                 continue
 
             # ---- one fused decode chunk --------------------------------
-            caches, tokj, posj, tstepj, toksj = self._chunk(
-                self.params, caches, jnp.asarray(tok), jnp.asarray(pos),
-                jnp.asarray(tstep), jnp.asarray(rids),
-                jnp.asarray(active), cfg.decode_chunk)
+            with phase("serve.chunk", active=int(active.sum())):
+                caches, tokj, posj, tstepj, toksj = self._chunk(
+                    self.params, caches, jnp.asarray(tok), jnp.asarray(pos),
+                    jnp.asarray(tstep), jnp.asarray(rids),
+                    jnp.asarray(active), cfg.decode_chunk)
             self.stats.chunk_calls += 1
-            # np.array (not asarray): device outputs view as read-only, and
-            # the admission path writes slots in place
-            tok, pos, tstep = (np.array(tokj), np.array(posj),
-                               np.array(tstepj))
-            toks = np.asarray(toksj)                      # (B, chunk)
+            with phase("serve.readback"):
+                # np.array (not asarray): device outputs view as read-only,
+                # and the admission path writes slots in place
+                tok, pos, tstep = (np.array(tokj), np.array(posj),
+                                   np.array(tstepj))
+                toks = np.asarray(toksj)                  # (B, chunk)
+            self.stats.readbacks += 4
 
             # ---- harvest + evict ---------------------------------------
             evicted = []
-            for s in range(B):
-                req = slots[s]
-                if req is None:
-                    continue
-                for t in toks[s]:
-                    req.generated.append(int(t))
-                    self.stats.tokens += 1
-                    if cfg.eos_id >= 0 and int(t) == cfg.eos_id:
-                        finish(s, req, "eos")
-                        evicted.append(s)
-                        break
-                    if len(req.generated) >= req.max_new_tokens:
-                        finish(s, req, "length")
-                        evicted.append(s)
-                        break
-            if cfg.kv == "paged":
-                for s in evicted:
-                    caches = self._retire_fn(caches,
-                                             jnp.asarray(s, jnp.int32))
-                    self.stats.retire_calls += 1
+            with phase("serve.harvest"):
+                for s in range(B):
+                    req = slots[s]
+                    if req is None:
+                        continue
+                    for t in toks[s]:
+                        req.generated.append(int(t))
+                        self.stats.tokens += 1
+                        if cfg.eos_id >= 0 and int(t) == cfg.eos_id:
+                            finish(s, req, "eos")
+                            evicted.append(s)
+                            break
+                        if len(req.generated) >= req.max_new_tokens:
+                            finish(s, req, "length")
+                            evicted.append(s)
+                            break
+            if cfg.kv == "paged" and evicted:
+                with phase("serve.retire"):
+                    for s in evicted:
+                        caches = self._retire_fn(caches,
+                                                 jnp.asarray(s, jnp.int32))
+                        self.stats.retire_calls += 1
         return out

@@ -77,6 +77,15 @@ def test_fused_decode_compiles_at_decode_shapes(one_chip, site):
     assert "tpu_custom_call" in hlo
 
 
+def test_fused_decode_keeps_its_op_name(one_chip):
+    # the benchmark reads the kernel's device time by the op name prefix
+    # ``fused_decode`` (bench/metrics/kernel.fused_ms_per_step.py)
+    hlo = _compile_kernel(one_chip, 8, D, D, per_row=True)
+    calls = [ln.split(" = ", 1)[0].strip().lstrip("%")
+             for ln in hlo.splitlines() if '"tpu_custom_call"' in ln]
+    assert calls and all(c.startswith("fused_decode") for c in calls)
+
+
 # DPPU recompute sources the ops wrapper picks (dppu_src, per-row weight
 # faults); the plan must hold for each where it admits any row count
 DPPU_VARIANTS = {"none": ("none", False), "reuse": ("reuse", False),
