@@ -194,29 +194,33 @@ def _attend(q, k, v, *, cfg, run, window, positions, cross, cache, mode,
     new_cache = cache
     if mode == "decode" and not cross and "bt" in cache:
         # paged cache: the slot's logical position maps through the block
-        # table to a physical row of the shared block pool.  Rows whose
-        # table entry is the trash block (id 0 — evicted/idle slots) write
-        # garbage nobody reads; rows with real blocks own them exclusively.
+        # table to a row of the shared block pool.  Rows whose table entry
+        # is the trash block (id 0 — evicted/idle slots) write garbage
+        # nobody reads; rows with real blocks own them exclusively.  Inside
+        # the layer scan the pools arrive whole, stacked over layers, with
+        # the layer index beside them (``layer``): the row write and the
+        # block gather then address the stack in place, so no layer's pool
+        # is ever copied out and back
         pool_k, pool_v, bt = cache["k"], cache["v"], cache["bt"]
-        P, bs = pool_k.shape[0], pool_k.shape[1]
+        lay = (cache["layer"],) if "layer" in cache else ()
+        bs = pool_k.shape[-2]
         eff_cap = bt.shape[1] * bs
         pos = positions[:, 0]                                        # (B,)
         slot = pos % window if window else jnp.minimum(pos, eff_cap - 1)
-        fi = bt[jnp.arange(B), slot // bs] * bs + slot % bs          # (B,)
-        kp = pool_k.reshape(P * bs, KH, Dh).at[fi].set(k[:, 0])
-        vp = pool_v.reshape(P * bs, KH, Dh).at[fi].set(v[:, 0])
-        new_cache = {"k": kp.reshape(pool_k.shape),
-                     "v": vp.reshape(pool_v.shape), "bt": bt}
+        at = (*lay, bt[jnp.arange(B), slot // bs], slot % bs)
+        kp = pool_k.at[at].set(k[:, 0].reshape(B, KH * Dh))
+        vp = pool_v.at[at].set(v[:, 0].reshape(B, KH * Dh))
+        new_cache = {"k": kp, "v": vp, "bt": bt}
         # gather this row's blocks back into slot order and run the same
         # count-masked decode attention as the dense layout (bit-identical:
-        # masked tail slots never contribute)
-        flat = (bt[:, :, None] * bs
-                + jnp.arange(bs)[None, None]).reshape(B, eff_cap)
-        # the pool is replicated over DP (global block ids) but the gathered
-        # per-row view is batch-major again — constrain it like the dense
-        # layout so attention runs DP/TP-sharded
-        kc = ac(kp[flat], "dp", None, "tp", None)        # (B, C, KH, Dh)
-        vc = ac(vp[flat], "dp", None, "tp", None)
+        # masked tail slots never contribute).  The pool is replicated over
+        # DP (global block ids) but the gathered per-row view is batch-major
+        # again — constrain it like the dense layout so attention runs
+        # DP/TP-sharded
+        kc = ac(kp[(*lay, bt)].reshape(B, eff_cap, KH, Dh),
+                "dp", None, "tp", None)                      # (B, C, KH, Dh)
+        vc = ac(vp[(*lay, bt)].reshape(B, eff_cap, KH, Dh),
+                "dp", None, "tp", None)
         n_valid = jnp.minimum(pos + 1, window if window else eff_cap)
         o = _decode_attn(q, kc, vc, n_valid, cap=cfg.attn_softcap)
     elif mode == "decode" and not cross:
@@ -289,11 +293,16 @@ def init_paged_cache(cfg, kind, batch, cap_len, block_size, n_blocks, dtype):
     starts there, and evicted slots are pointed back at it, so idle rows'
     decode writes land in memory nobody reads.  Rolling (window) layers keep
     the same slot map as the dense layout (position p at slot p % window),
-    just block-indexed; their table is window-sized."""
+    just block-indexed; their table is window-sized.
+
+    A slot's row is lane-dense, ``KH * Dh`` wide with the heads major: a
+    ``(KH, Dh)`` row would pad ``Dh`` to the TPU's 128 lanes and make the
+    compiler lay the stacked pool out block-dim minor, so every layer's
+    slice of it became a transposing copy."""
     window = cfg.window if kind == "L" else 0
     cap = window if window else cap_len
     width = -(-cap // block_size)                    # ceil
-    shp = (n_blocks, block_size, cfg.n_kv_heads, cfg.d_head)
+    shp = (n_blocks, block_size, cfg.n_kv_heads * cfg.d_head)
     return {"k": jnp.zeros(shp, dtype), "v": jnp.zeros(shp, dtype),
             "bt": jnp.zeros((batch, width), jnp.int32)}
 

@@ -176,6 +176,28 @@ def _cross_kv(pa, enc_out, cfg, ftc, name):
 
 
 # -------------------------------------------------------------- backbone ---
+def _layer_caches(cs, i):
+    """Layer ``i``'s view of scan-stacked caches.  A paged attention cache
+    (``k``/``v`` pools beside a ``bt`` block table) keeps its pools whole,
+    stacked ``(n_rep, n_blocks, block_size, KH*Dh)``, with ``layer`` = i
+    beside them; every other leaf is sliced to its layer."""
+    if "bt" in cs:
+        return {"k": cs["k"], "v": cs["v"], "bt": cs["bt"][i], "layer": i}
+    return {n: _layer_caches(c, i) if isinstance(c, dict) else c[i]
+            for n, c in cs.items()}
+
+
+def _write_layer(cs, nc, i):
+    """Inverse of ``_layer_caches``: the pools layer ``i`` updated in place
+    replace the stacked ones; sliced leaves are written back at ``i``.
+    Decode never changes a block table, so ``bt`` is kept as it was."""
+    if "bt" in cs:
+        return {"k": nc["k"], "v": nc["v"], "bt": cs["bt"]}
+    return {n: (_write_layer(c, nc[n], i) if isinstance(c, dict) else
+                jax.lax.dynamic_update_index_in_dim(c, nc[n], i, 0))
+            for n, c in cs.items()}
+
+
 def backbone(params, x, *, cfg, run, mode="train", caches=None,
              positions=None, probe=None, ftc=None, enc_out=None):
     """Apply all layers.  Returns (hidden, new_caches, aux_loss_sum)."""
@@ -226,20 +248,20 @@ def backbone(params, x, *, cfg, run, mode="train", caches=None,
         if run.remat == "block":
             body = jax.checkpoint(sb, prevent_cse=False)
         if caches is not None:
-            # decode carries the stacked caches through the layer scan and
-            # writes each layer's slice back in place: emitted as scan
-            # outputs they would be a fresh copy of the whole KV cache per
-            # step, which a decode loop's while-carry multiplies (~5 copies
-            # at full width — more than a 16 GB chip holds)
+            # decode carries the stacked caches through the layer scan:
+            # emitted as scan outputs they would be a fresh copy of the
+            # whole KV cache per step, which a decode loop's while-carry
+            # multiplies (~5 copies at full width — more than a 16 GB chip
+            # holds).  Paged pools stay whole in the carry and attention
+            # writes and gathers its layer's rows in place (see
+            # _layer_caches); every other leaf — block tables, R/S state,
+            # cross-attention buffers, dense KV — is a per-layer slice
+            # written back into the stack
             def dec(carry, inp, sb=sb):
                 x, aux, cs = carry
                 blk_p, i = inp
-                (x, aux), nc = sb((x, aux),
-                                  (blk_p, jax.tree.map(lambda c: c[i], cs)))
-                cs = jax.tree.map(
-                    lambda c, n: jax.lax.dynamic_update_index_in_dim(
-                        c, n, i, 0), cs, nc)
-                return (x, aux, cs), None
+                (x, aux), nc = sb((x, aux), (blk_p, _layer_caches(cs, i)))
+                return (x, aux, _write_layer(cs, nc, i)), None
 
             (x, aux_total, seg_caches), _ = jax.lax.scan(
                 dec, (x, aux_total, caches[f"seg{si}"]),

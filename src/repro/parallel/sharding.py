@@ -102,19 +102,24 @@ def batch_shardings(batch_tree, mesh: Mesh):
     return jax.tree.map(one, batch_tree)
 
 
-def cache_shardings(cache_tree, mesh: Mesh, unrolled: bool = False):
+def cache_shardings(cache_tree, mesh: Mesh, unrolled: bool = False,
+                    kv_heads: int | None = None):
     """KV/state caches: batch over DP, head/width dims over 'model' when they
     divide.  Cache layouts (leading 'blocks' stack dim unless unrolled):
       attn k/v: (B, C, KH, Dh); rglru h: (B, W), conv: (B, K-1, W);
       ssd state: (B, H, P, N), conv: (B, K-1, C).
 
     Paged attention caches (a ``bt`` block table beside ``k``/``v``) store a
-    *pool* ``(n_blocks, block_size, KH, Dh)``: block tables hold **global**
+    *pool* ``(n_blocks, block_size, KH*Dh)``: block tables hold **global**
     block ids, so the pool dim (and the block dim) must stay replicated over
     the DP axes — sharding dim 0 as if it were batch would break every
-    table lookup.  Pools shard on kv heads over 'model' only (no split-K
-    fallback: the in-block dim is ``block_size``, not cache length); the
-    table itself is per-slot state and shards with the batch."""
+    table lookup.  A pool row holds its heads major and ``Dh`` minor, so
+    splitting its last dim over 'model' is a split over kv heads; it is made
+    only when ``kv_heads`` (the model's ``n_kv_heads``) divides the axis,
+    so no head is cut (no split-K fallback either: the in-block dim is
+    ``block_size``, not cache length).  Without ``kv_heads`` pools stay
+    replicated.  The table itself is per-slot state and shards with the
+    batch."""
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     # paged pool detection: any cache dict holding a block table holds pools
     leaves = jax.tree_util.tree_flatten_with_path(cache_tree)[0]
@@ -129,10 +134,11 @@ def cache_shardings(cache_tree, mesh: Mesh, unrolled: bool = False):
         name = names[-1]
         paged = tuple(names[:-1]) in pooled
         if paged and name in ("k", "v"):
-            # (n_blocks, block_size, KH, Dh): pool + block dims replicated
+            # (n_blocks, block_size, KH*Dh): pool + block dims replicated,
+            # whole kv heads over 'model'
             entries = [None] * len(shape)
-            if len(shape) == 4:
-                entries[2] = _maybe(mesh, shape[2], "model")
+            if kv_heads and _maybe(mesh, kv_heads, "model"):
+                entries[-1] = "model"
         else:
             entries = [_maybe(mesh, shape[0], dp)] + [None] * (len(shape) - 1)
             if not paged and name in ("k", "v", "ck", "cv") and len(shape) == 4:

@@ -15,12 +15,14 @@ A fixed pool of ``max_batch`` decode *slots* serves a queue of requests:
 KV layouts (``cfg.kv``):
 
   * ``"paged"`` (default) — attention KV lives in a per-layer *block pool*
-    ``(n_blocks, block_size, KH, Dh)`` addressed through a per-slot block
-    table.  Block 0 is the trash block: idle/evicted slots point at it, so
-    their decode writes land in memory nobody reads, and prefill scatters
-    use drop-mode sentinels so pad positions write nowhere at all.  A
-    request only occupies ``ceil((plen + max_new)/block_size)`` blocks
-    (plus ``ceil(window/block_size)`` for sliding-window layers), so short
+    ``(n_blocks, block_size, KH*Dh)`` of lane-dense rows addressed through
+    a per-slot block table; the decode layer scan writes and gathers the
+    layer-stacked pools in place.  Block 0 is the trash block: idle/evicted
+    slots point at it, so their decode writes land in memory nobody reads,
+    and prefill scatters use drop-mode sentinels so pad positions write
+    nowhere at all.  A request only occupies
+    ``ceil((plen + max_new)/block_size)`` blocks (plus
+    ``ceil(window/block_size)`` for sliding-window layers), so short
     requests don't reserve worst-case capacity — admission is bounded by
     free *blocks*, not uniform slot capacity.
   * ``"dense"`` — the PR 3 layout: every slot owns a capacity-sized cache
@@ -168,7 +170,8 @@ class Scheduler:
             if mesh is None:
                 return caches
             return jax.lax.with_sharding_constraint(
-                caches, S.cache_shardings(caches, mesh))
+                caches, S.cache_shardings(caches, mesh,
+                                          kv_heads=model.cfg.n_kv_heads))
 
         mcfg = model.cfg
         kinds = T._layer_kinds(mcfg)
@@ -259,18 +262,23 @@ class Scheduler:
                 return caches, tok0[0]
 
         def _scatter_pool(pool, rows, bt_row, wdw, plen):
-            # pool (P, bs, KH, Dh); rows (1, S1, KH, Dh).  Prefill positions
-            # land at their logical slot's physical row; positions past the
-            # request's real length (bucket pads, capacity growth) get a
-            # sentinel index and are dropped — they write nowhere.
-            P = pool.shape[0]
-            S1 = rows.shape[1]
+            # pool (..., P, bs, KH*Dh), a layer's or stacked over layers;
+            # rows (..., 1, S1, KH, Dh).  Prefill position p lands at row
+            # p % bs of its logical block's physical block; positions past
+            # the request's real length (bucket pads, capacity growth) get
+            # the sentinel block P and are dropped — they write nowhere.
+            # A stacked pool's layer dim is indexed too, not sliced: a
+            # scatter at (layer, block, offset) updates the stack in place,
+            # where a sliced layer dim makes XLA relayout the whole stack.
+            P = pool.shape[-3]
+            S1 = rows.shape[-3]
             idx = jnp.arange(S1)
             valid_n = jnp.minimum(plen, wdw) if wdw else plen
-            fi = bt_row[idx // bs] * bs + idx % bs
-            fi = jnp.where(idx < valid_n, fi, P * bs)
-            pf = pool.reshape(P * bs, *pool.shape[2:])
-            return pf.at[fi].set(rows[0], mode="drop").reshape(pool.shape)
+            blk = jnp.where(idx < valid_n, bt_row[idx // bs], P)
+            lay = ((jnp.arange(pool.shape[0])[:, None],) if pool.ndim == 4
+                   else ())
+            rows = rows.reshape(*pool.shape[:-3], S1, pool.shape[-1])
+            return pool.at[(*lay, blk, idx % bs)].set(rows, mode="drop")
 
         def _insert(caches, c1, slot, plen, bt_g, bt_l):
             # one executable for both layouts: paged attention leaves are
@@ -290,18 +298,11 @@ class Scheduler:
                         row = bt_l if wdw else bt_g
                         scat = partial(_scatter_pool, bt_row=row, wdw=wdw,
                                        plen=plen)
-                        if stacked:
-                            out[nm] = {
-                                "k": jax.vmap(scat)(sub["k"], dsub["k"]),
-                                "v": jax.vmap(scat)(sub["v"], dsub["v"]),
-                                "bt": sub["bt"].at[:, slot].set(row),
-                            }
-                        else:
-                            out[nm] = {
-                                "k": scat(sub["k"], dsub["k"]),
-                                "v": scat(sub["v"], dsub["v"]),
-                                "bt": sub["bt"].at[slot].set(row),
-                            }
+                        out[nm] = {
+                            "k": scat(sub["k"], dsub["k"]),
+                            "v": scat(sub["v"], dsub["v"]),
+                            "bt": sub["bt"].at[..., slot, :].set(row),
+                        }
                     elif nm == "cross":
                         s1e = dsub["ck"].shape[-3]
                         if stacked:
@@ -470,7 +471,8 @@ class Scheduler:
         caches = self._init_caches(B)
         if self.mesh is not None:
             caches = jax.device_put(
-                caches, S.cache_shardings(caches, self.mesh))
+                caches, S.cache_shardings(
+                    caches, self.mesh, kv_heads=self.model.cfg.n_kv_heads))
         tok = np.zeros((B,), np.int32)
         pos = np.zeros((B,), np.int32)
         tstep = np.zeros((B,), np.int32)

@@ -1,5 +1,7 @@
 """Continuous-batching scheduler: eviction, bucket reuse, per-request
 fault-stream independence."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -153,11 +155,28 @@ def test_scheduler_guards(danube):
                           max_new_tokens=4)])
 
 
-def test_paged_matches_dense(danube):
+# the paged pool in the python-loop layers, and in place in the layer
+# scan's carry: 3 scanned sliding-window layers whose window of 8 the
+# requests' positions (up to 14) wrap, and scanned super-blocks of a global
+# and a local layer, whose two pools differ in table width
+PAGED_CASES = {
+    "unrolled": {},
+    "scanned_wrap": {"unroll": False, "n_layers": 3, "window": 8},
+    "scanned_global_local": {"unroll": False, "n_layers": 4, "window": 8,
+                             "block_pattern": ("G", "L")},
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_CASES))
+def test_paged_matches_dense(danube, case):
     """The paged KV cache is a pure layout change: the same workload through
     kv='paged' and kv='dense' yields bit-identical tokens, even with a
     deliberately tight block pool that forces requests to wait for blocks."""
     cfg, m, params = danube
+    if PAGED_CASES[case]:
+        cfg = dataclasses.replace(cfg, **PAGED_CASES[case])
+        m = build(cfg)
+        params = m.init(jax.random.PRNGKey(0))
     mk = lambda: [Request(rid=i, tokens=_prompt(3 + 2 * (i % 3), cfg.vocab,
                                                 20 + i),
                           max_new_tokens=5 + (i % 2)) for i in range(5)]

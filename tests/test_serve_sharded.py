@@ -170,14 +170,15 @@ def test_fold_axis_index_shard_map_contract():
 
 @pytest.mark.slow
 def test_paged_pool_replicated_on_real_mesh():
-    """The satellite-1 regression on real devices: paged pool leaves are
-    fully addressable from every DP shard (pool dim replicated), while dense
-    per-slot rows shard over the batch."""
+    """The satellite-1 regression on real devices: paged pool leaves
+    ``(n_blocks, block_size, KH*Dh)`` are fully addressable from every DP
+    shard (pool and block dims replicated) and split their heads-major rows
+    over 'model', while dense per-slot rows shard over the batch."""
     out = run_py(_SETUP + """
     from repro.parallel import sharding as S
     cfg, m, params = load('h2o-danube-1.8b')
     caches = m.init_cache(4, 16, paged=(8, 17))
-    sh = S.cache_shardings(caches, mesh)
+    sh = S.cache_shardings(caches, mesh, kv_heads=cfg.n_kv_heads)
 
     def leaves_with_paths(tree):
         return jax.tree_util.tree_flatten_with_path(tree)[0]
@@ -196,6 +197,9 @@ def test_paged_pool_replicated_on_real_mesh():
             # pool + block dims replicated: addressable from every shard
             assert spec[off] is None and spec[off + 1] is None, (names,
                                                                  s.spec)
+            # heads split on the last dim: each shard holds whole kv heads
+            assert len(s.spec) == off + 3 and s.spec[-1] == 'model', (
+                names, s.spec)
             pool_seen += 1
         if names[-1] == 'bt':
             assert 'data' in axes(spec[off]), (names, s.spec)

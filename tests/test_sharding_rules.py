@@ -78,18 +78,18 @@ def test_cache_heads_preferred_when_divisible():
 
 
 def test_paged_pool_never_dp_sharded():
-    """Regression: a paged pool leaf (n_blocks, block_size, KH, Dh) used to
+    """Regression: a paged pool leaf (n_blocks, block_size, KH*Dh) used to
     match the dense (B, C, KH, Dh) branch and get its *pool* dim DP-sharded
     as if it were batch — but block tables hold global block ids, so any
-    sharding of dims 0/1 breaks paged lookup.  Pools shard on kv heads over
-    'model' only; the block table itself shards with the batch."""
-    pool = (4096, 16, 16, 128)     # divisible by 16 on dims 0/1/2: tempting
+    sharding of dims 0/1 breaks paged lookup.  Pools shard their heads-major
+    rows over 'model' only; the block table itself shards with the batch."""
+    pool = (4096, 16, 16 * 128)    # divisible by 16 on dims 0/1/2: tempting
     cache = {"seg0": {"s0": {"attn": {
         "k": sds((23,) + pool), "v": sds((23,) + pool),
         "bt": jax.ShapeDtypeStruct((23, 256, 32), jnp.int32)}}}}
-    sh = S.cache_shardings(cache, MESH)
+    sh = S.cache_shardings(cache, MESH, kv_heads=16)
     attn = jax.tree.leaves(sh["seg0"]["s0"]["attn"]["k"])[0].spec
-    assert attn == P(None, None, None, "model", None)
+    assert attn == P(None, None, None, "model")
     assert jax.tree.leaves(sh["seg0"]["s0"]["attn"]["v"])[0].spec == attn
     # the per-slot block table is batch-major state: batch over DP
     assert jax.tree.leaves(sh["seg0"]["s0"]["attn"]["bt"])[0].spec == \
@@ -99,14 +99,16 @@ def test_paged_pool_never_dp_sharded():
 def test_paged_pool_heads_indivisible_stays_replicated():
     # no split-K fallback for pools: the in-block dim is block_size, not
     # cache length, so an indivisible head count leaves the pool replicated
-    pool = (4096, 16, 2, 128)
+    # — even where the row width KH*Dh (2 x 128) divides the axis, a split
+    # would cut heads
+    pool = (4096, 16, 2 * 128)
     cache = {"l0": {"attn": {"k": sds(pool), "v": sds(pool),
                              "bt": jax.ShapeDtypeStruct((8, 32), jnp.int32)}}}
-    sh = S.cache_shardings(cache, MESH)
+    sh = S.cache_shardings(cache, MESH, kv_heads=2)
     assert jax.tree.leaves(sh["l0"]["attn"]["k"])[0].spec == \
-        P(None, None, None, None)
+        P(None, None, None)
     # dense siblings (cross-attn buffers etc.) keep the dense rules
     cache["l0"]["cross"] = {"ck": sds((32, 128, 16, 128))}
-    sh = S.cache_shardings(cache, MESH)
+    sh = S.cache_shardings(cache, MESH, kv_heads=2)
     assert jax.tree.leaves(sh["l0"]["cross"]["ck"])[0].spec == \
         P(("data",), None, "model", None)

@@ -12,13 +12,20 @@ compile (never run) at h2o-danube-1.8b widths (d_model 2560, d_ff 6912):
     with no kernel in them;
   * the largest row count the plan admits at each width, for every DPPU
     recompute source (one global ``t``; per-row with per-row weight
-    faults) — the plan must never admit a shape the compiler refuses.
+    faults) — the plan must never admit a shape the compiler refuses;
+  * the scheduler's decode chunk (clean and under crt3) and its insert at
+    the benchmark's size: the paged KV pool stays in place, with no slice,
+    update-slice or copy of a layer's pool or of the layer stack.
 
 The topology is described inside a fixture (never at import), so every
 pytest-xdist worker collects the same tests and only the worker that runs
 this file loads the TPU library.
 """
+import json
+import math
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -26,9 +33,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import ft
+from repro.configs.base import ModelConfig, RunConfig
 from repro.kernels.fused_decode.kernel import fused_decode
 from repro.kernels.fused_decode.ops import fused_protect_linear, kernel_fits
+from repro.models import build
+from repro.serve.scheduler import Scheduler, SchedulerConfig
 
+ROOT = Path(__file__).resolve().parents[1]
 D, F, KV = 2560, 6912, 640     # h2o-danube-1.8b d_model, d_ff, 8 kv heads x 80
 DECODE_SHAPES = {"wq_wo": (D, D), "wk_wv": (D, KV), "ffn_up_gate": (D, F),
                  "ffn_down": (F, D)}
@@ -134,3 +145,82 @@ def test_fused_protect_linear_routes_and_compiles(one_chip, case):
     assert kernel_fits(m, n, perrow_wf=wf and per_row) == kernel
     hlo = _compile_protect(one_chip, policy, m, k, n, m if per_row else 0)
     assert ("tpu_custom_call" in hlo) == kernel
+
+
+# a pool-sized move: an op that slices, updates a slice of or copies an
+# array of one or more layers' pools, in any shape (a copy-start's tuple too)
+_MOVE = re.compile(r" = (.*?) (dynamic-slice|dynamic-update-slice|copy|"
+                   r"copy-start|copy-done)\(")
+
+
+def _pool_moves(hlo, layer_elems):
+    moves = []
+    for ln in hlo.splitlines():
+        m = _MOVE.search(ln)
+        if m and any(n and n % layer_elems == 0 for n in (
+                math.prod(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"\[([\d,]*)\]", m.group(1)))):
+            moves.append(ln.strip()[:160])
+    return moves
+
+
+def _bench_scheduler(config):
+    """The scheduler of a benchmark configuration (``bench/configs/``) with
+    abstract weights.  danube's: 24 layers, 8 slots, buckets up to 512, 256
+    new tokens, blocks of 8, so the pool is 1 + 8 x (96 + 512) = 4865
+    blocks a layer.  Returns it, its cache shapes and one layer's pool size
+    in elements."""
+    conf = json.loads((ROOT / "bench" / "configs" / f"{config}.json")
+                      .read_text())
+    mconf = dict(conf["model"], block_pattern=tuple(
+        conf["model"]["block_pattern"]))
+    model = build(ModelConfig(**mconf), RunConfig(**conf["run"]))
+    prot, kw = conf["protection"], {}
+    if prot:
+        kw = {"policy": ft.get_policy(prot["policy"], ber=prot["ber"],
+                                      weight_faults=prot["weight_faults"]),
+              "ft_backend": prot["backend"]}
+    scfg = dict(conf["scheduler"], buckets=tuple(
+        conf["scheduler"]["buckets"]))
+    sched = Scheduler(model, jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                      SchedulerConfig(**scfg), **kw)
+    caches = jax.eval_shape(lambda: sched._init_caches(scfg["max_batch"]))
+    pool = caches["seg0"]["s0"]["attn"]["k"]
+    assert pool.shape == (24, 4865, 8, KV)
+    return sched, caches, math.prod(pool.shape[1:])
+
+
+def _on(sharding, tree):
+    return jax.tree.map(lambda x: _sds(x.shape, x.dtype, sharding), tree)
+
+
+@pytest.mark.parametrize("config", ["danube-1.8b", "danube-1.8b-crt3"])
+def test_decode_chunk_keeps_pool_in_place(one_chip, config):
+    """The layer scan writes and gathers the stacked pool in place: a
+    per-layer slice of it was a transposing copy in and out of every layer,
+    and moving 2 x 50 MB a layer that way cost about a quarter of each
+    decode step."""
+    sched, caches, layer_elems = _bench_scheduler(config)
+    row = _sds((8,), jnp.int32, one_chip)
+    compiled = sched._chunk.lower(
+        _on(one_chip, sched.params), _on(one_chip, caches), row, row, row,
+        row, _sds((8,), jnp.bool_, one_chip), 4).compile()
+    assert _pool_moves(compiled.as_text(), layer_elems) == []
+    # temporaries under the stacked K pool (1.2 GB), so no copy of the
+    # stack hides in them: slicing it out per layer took 4.2 GB here
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 24 * layer_elems * 2
+
+
+def test_insert_keeps_pool_in_place(one_chip):
+    """Admission scatters a 512-token prefill's rows into the stacked pool
+    in place, with no relayout copy of the stack in and out."""
+    sched, caches, layer_elems = _bench_scheduler("danube-1.8b")
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    c1, _ = jax.eval_shape(sched._prefill_one, sched.params,
+                           {"tokens": i32(1, 512)}, i32(1), i32())
+    compiled = sched._insert.lower(
+        _on(one_chip, caches), _on(one_chip, c1),
+        *_on(one_chip, (i32(), i32(), i32(sched._wg), i32(sched._wl)))
+    ).compile()
+    assert _pool_moves(compiled.as_text(), layer_elems) == []

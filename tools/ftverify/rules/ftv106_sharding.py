@@ -96,8 +96,8 @@ def check_paged_pool_specs(finding) -> list:
                 ("data", "model"))
     tree = {
         "l0": {"attn": {
-            "k": sds((16, 8, 2, 4), jnp.bfloat16),   # pool (P, bs, KH, Dh)
-            "v": sds((16, 8, 2, 4), jnp.bfloat16),
+            "k": sds((16, 8, 2 * 4), jnp.bfloat16),  # pool (P, bs, KH*Dh)
+            "v": sds((16, 8, 2 * 4), jnp.bfloat16),
             "bt": sds((4, 2), jnp.int32),            # per-slot block table
         }},
         "l1": {"attn": {                             # dense (B, C, KH, Dh)
@@ -106,7 +106,7 @@ def check_paged_pool_specs(finding) -> list:
             "pos": sds((4,), jnp.int32),
         }},
     }
-    sh = cache_shardings(tree, mesh)
+    sh = cache_shardings(tree, mesh, kv_heads=2)
     out = []
     for nm in ("k", "v"):
         spec = sh["l0"]["attn"][nm].spec
@@ -117,10 +117,10 @@ def check_paged_pool_specs(finding) -> list:
                 f"{spec} — block tables hold global block ids, so the pool "
                 f"and block dims must stay DP-replicated or every lookup "
                 f"reads another shard's rows"))
-        if len(spec) > 2 and spec[2] != "model":
+        if spec[-1] != "model":
             out.append(finding(
                 f"paged-pool/{nm}",
-                f"paged {nm} pool kv-head dim is {spec[2]!r}, expected "
+                f"paged {nm} pool row (kv heads) dim is {spec[-1]!r}, expected "
                 f"'model' — the pool would be fully replicated over TP"))
         bt = sh["l0"]["attn"]["bt"].spec
         if bt and bt[0] not in (("data",), "data", None):
