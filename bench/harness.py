@@ -10,6 +10,13 @@ Everything that belongs to one cell is data found by name under ``bench/``:
   bench/metrics/<m>.py      one reader per metric: ``read(rec) -> value``
   bench/limits/<cell>.json  the limits of the cell's correctness check
 
+A cell on several chips is one deployment sharded over them: a mesh of
+``data`` 1 by ``model`` ``chips`` (the axis names of ``repro.launch.mesh``
+and ``repro.parallel.ctx``) over the devices the cell was given, each weight
+drawn straight into its shard of the serving layout (``Scheduler``'s,
+tensor-parallel over ``model``), and the scheduler serving under the mesh.
+A cell on one chip is served with no mesh.
+
 A later cell, configuration, traffic mix or metric is added as new files and
 entries, with no edit to code that is here.
 """
@@ -84,12 +91,16 @@ def read_metrics(metrics: list, rec: dict, root: Path = ROOT) -> dict:
     return out
 
 
-def build_system(conf: dict, seed: int):
+def build_system(conf: dict, seed: int, devices):
     """(model, params, scheduler) for a configuration file, weights drawn
-    from the seed (``bench.weights``)."""
+    from the seed (``bench.weights``); sharded over ``devices`` where there
+    are several."""
+    import jax
     from repro import ft
     from repro.configs.base import ModelConfig, RunConfig
+    from repro.launch.mesh import make_mesh
     from repro.models import build
+    from repro.parallel import sharding as S
     from repro.serve.scheduler import Scheduler, SchedulerConfig
 
     from bench.weights import make_params
@@ -99,7 +110,13 @@ def build_system(conf: dict, seed: int):
         if k in m:
             m[k] = tuple(m[k])
     model = build(ModelConfig(**m), RunConfig(**conf["run"]))
-    params = make_params(model, seed)
+    mesh = shardings = None
+    if len(devices) > 1:
+        mesh = make_mesh((1, len(devices)), ("data", "model"), devices=devices)
+        shardings = S.param_shardings(
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)), mesh,
+            no_fsdp=True)
+    params = make_params(model, seed, shardings)
     s = dict(conf["scheduler"])
     s["buckets"] = tuple(s["buckets"])
     # the scheduler's own seed is a constant of its compiled programs, so it
@@ -113,7 +130,7 @@ def build_system(conf: dict, seed: int):
                                weight_faults=prot["weight_faults"])
         backend = prot["backend"]
     return model, params, Scheduler(model, params, scfg, policy=policy,
-                                    ft_backend=backend)
+                                    ft_backend=backend, mesh=mesh)
 
 
 def rid_base(seed: int) -> int:

@@ -1,8 +1,9 @@
-"""The decode step's share of the chip's bf16 peak: model FLOPs of the
-wave's decoded tokens per step (matmul parameters, attention at each
-token's real context, the LM head; averaged over the wave's steps) over the
-device time per step of the decode-chunk program (``_chunk``) in the traced
-slice, times the peak."""
+"""The decode step's share of the bf16 peak of all the cell's chips: model
+FLOPs of the wave's decoded tokens per step (matmul parameters, attention at
+each token's real context, the LM head; averaged over the wave's steps) over
+the device time per step of the decode-chunk program (``_chunk``) in the
+traced slice, times ``chips`` times one chip's peak.  On several chips the
+step time is the mean over the chips (``trace.reduce``)."""
 from bench import work
 
 
@@ -16,4 +17,5 @@ def read(rec):
     step_s = t["modules"]["_chunk"] / (calls * chunk)
     m = rec["conf"]["model"]
     flops = sum(work.request_decode_flops(m, p, n) for p, n in rec["requests"]) / steps
-    return 100.0 * flops / (step_s * rec["peaks"]["bf16_flops_per_s"])
+    peak = rec["chips"] * rec["peaks"]["bf16_flops_per_s"]
+    return 100.0 * flops / (step_s * peak)

@@ -1,8 +1,10 @@
-"""Model FLOPs over the traced slice's length times the chip's bf16 peak:
-the FLOPs the model needs per prefill call and per decode step, averaged
-over the wave (real prompt and generated tokens; padding and protection's
-redundant work left out), times the calls of the prefill and decode-chunk
-programs in the slice."""
+"""Model FLOPs over the traced slice's length times the bf16 peak of all
+the cell's chips (``chips`` times one chip's): the FLOPs the model needs per
+prefill call and per decode step, averaged over the wave (real prompt and
+generated tokens; padding and protection's redundant work left out), times
+the calls of the prefill and decode-chunk programs in the slice.  On several
+chips the calls are the mean over the chips (``trace.reduce``), the same on
+each chip of one sharded program."""
 from bench import work
 
 
@@ -17,4 +19,7 @@ def read(rec):
     flops = (pre / st["prefill_calls"] * t["module_calls"].get("_prefill_one", 0.0)
              + dec / (st["chunk_calls"] * chunk)
              * t["module_calls"].get("_chunk", 0.0) * chunk)
-    return 100.0 * flops / (t["window_s"] * rec["peaks"]["bf16_flops_per_s"])
+    if not flops:
+        return None
+    peak = rec["chips"] * rec["peaks"]["bf16_flops_per_s"]
+    return 100.0 * flops / (t["window_s"] * peak)

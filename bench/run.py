@@ -21,7 +21,8 @@ data file under ``bench/`` (see ``bench/harness.py``).  A run
    reference (``bench/check.py``), after the program's state is freed;
 4. prints the metrics: with ``--trace 0`` the cell's end-to-end metrics, with
    ``--trace 1`` its per-layer metrics, read from a profiler trace of the
-   first ``SLICE_S`` seconds of its one wave (that run serves one wave).
+   first ``SLICE_S`` seconds of its one wave (that run serves one wave),
+   divided by the cell's chips.
 
 The last line of standard output is one JSON object; the numbers compared
 for ``correct`` come last in it, under ``check``, and as the last lines of
@@ -47,7 +48,11 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 from bench import check, harness, traffic  # noqa: E402
 
 WARM_RID = 1 << 30     # request ids of the warm-up, apart from the window's
-SLICE_S = 10.0         # the traced slice: the first seconds of the wave
+# the traced slice, the first seconds of the wave, on one chip.  Stopping the
+# profiler takes time in proportion to the device events it holds, which
+# grow with the chips traced (10 s of one chip took 60-200 s to stop, of four
+# 614 s), so a cell on n chips traces SLICE_S / n seconds.
+SLICE_S = 10.0
 
 
 class CompileClock:
@@ -69,12 +74,13 @@ class Poller(threading.Thread):
     """Stamps when each watched request's token count grows.
 
     With ``trace_dir`` it also takes the profiler trace of one slice: from
-    its first poll, just before the wave starts, for ``SLICE_S`` seconds or
+    its first poll, just before the wave starts, for ``slice_s`` seconds or
     to the end of the wave, under a ``bench.slice`` span.  A slice, not the
     whole wave, keeps the trace small enough to write and read within a
     run."""
 
-    def __init__(self, period: float = 1e-3, trace_dir: str | None = None):
+    def __init__(self, period: float = 1e-3, trace_dir: str | None = None,
+                 slice_s: float = SLICE_S):
         super().__init__(daemon=True)
         self.period, self.reqs, self.seen = period, [], {}
         self.t_close, self.count, self.in_window = float("inf"), 0, 0
@@ -83,6 +89,7 @@ class Poller(threading.Thread):
         self.worst, self.worst_at = 0.0, 0.0    # the latest poll, and when
         self._lock = threading.Lock()
         self.trace_dir, self._slice, self._t_slice = trace_dir, None, None
+        self.slice_s = slice_s
         self.sliced = False
 
     def watch(self, reqs):
@@ -111,7 +118,7 @@ class Poller(threading.Thread):
             if self.trace_dir and not self.sliced:
                 if self._slice is None:
                     self._start_slice(now)
-                elif self._slice is not None and now - self._t_slice >= SLICE_S:
+                elif now - self._t_slice >= self.slice_s:
                     self._end_slice()
 
     def _start_slice(self, now):
@@ -173,10 +180,18 @@ def warmup_requests(conf: dict, spec: dict) -> list:
 
 
 def _sum_stats(stats) -> dict:
+    """The waves' ``SchedStats`` as one: counts and host seconds per phase
+    summed, peaks and each phase's longest span the largest."""
     keys = ("prefill_calls", "insert_calls", "chunk_calls", "retire_calls",
-            "tokens", "roundtrips")
+            "tokens", "roundtrips", "readbacks")
     out = {k: sum(getattr(s, k) for s in stats) for k in keys}
     out["blocks_in_use_peak"] = max(s.blocks_in_use_peak for s in stats)
+    out["host_s"], out["host_max_s"] = {}, {}
+    for s in stats:
+        for k, v in s.host_s.items():
+            out["host_s"][k] = out["host_s"].get(k, 0.0) + v
+        for k, v in s.host_max_s.items():
+            out["host_max_s"][k] = max(out["host_max_s"].get(k, 0.0), v)
     return out
 
 
@@ -201,7 +216,7 @@ def run_cell(w: dict, seed: int, seconds: float, trace: bool, devices,
     conf, spec = w["conf"], w["traffic_spec"]
     vocab = conf["model"]["vocab"]
     t0 = time.perf_counter()
-    model, params, sched = harness.build_system(conf, seed)
+    model, params, sched = harness.build_system(conf, seed, devices)
     jax.block_until_ready(params)
     t1 = time.perf_counter()
     sched.run(warmup_requests(conf, spec))
@@ -213,7 +228,7 @@ def run_cell(w: dict, seed: int, seconds: float, trace: bool, devices,
         f"compile_s={setup_compile_s} compiles={setup_compiles}")
 
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
-    poller = Poller(trace_dir=trace_dir)
+    poller = Poller(trace_dir=trace_dir, slice_s=SLICE_S / len(devices))
     poller.start()
     gen = traffic.waves(spec, seed, vocab, harness.rid_base(seed))
     done, stats, n_waves = [], [], 0
@@ -270,8 +285,9 @@ def run_cell(w: dict, seed: int, seconds: float, trace: bool, devices,
         "window_s": window_s, "tokens": tokens, "tpot_ms": tpot,
         "requests": [(len(r.tokens), len(r.generated)) for r in done],
         "stats": _sum_stats(stats), "memory_peak_bytes": mem_peak,
-        "peaks": peak, "trace": None,
+        "peaks": peak, "chips": len(devices), "trace": None,
     }
+    log(f"counters: {json.dumps(rec['stats'])}")
     del model, params, sched, done, reqs
     gc.collect()
 
@@ -292,16 +308,23 @@ def run_cell(w: dict, seed: int, seconds: float, trace: bool, devices,
     result = {"correct": bool(not failed and ok),
               "attempted": len(rec["requests"]), "failed": len(failed)}
     if trace:
+        from bench import scopes
         from bench import trace as tr
         t4 = time.perf_counter()
-        devs, host, marks = tr.load(tr.xplane_file(trace_dir))
-        rec["trace"] = tr.reduce(devs, host, *marks["bench.slice"])
+        t = tr.load(tr.xplane_file(trace_dir), {d.id for d in devices})
+        lo, hi = t.marks["bench.slice"]
+        rec["trace"] = tr.reduce(t.devices, t.host, lo, hi, spans=t.spans)
+        rec["trace"]["scopes"] = scopes.scope_times(
+            t.devices, scopes.program_scopes(t.xspace), lo, hi)
+        del t
         shutil.rmtree(trace_dir, ignore_errors=True)
         device["busy_s"] = rec["trace"]["busy_s"]
         device["window_s"] = rec["trace"]["window_s"]
         log(f"trace: read_s={time.perf_counter() - t4} "
             f"modules={json.dumps(rec['trace']['modules'])} "
-            f"calls={json.dumps(rec['trace']['module_calls'])}")
+            f"calls={json.dumps(rec['trace']['module_calls'])} "
+            f"scopes={json.dumps(rec['trace']['scopes'])} "
+            f"idle_by_span={json.dumps(rec['trace']['idle_by_span'])}")
         result["metrics"] = harness.read_metrics(w["per_layer"], rec, root)
         result["breakdown"] = {k: rec["trace"][k]
                                for k in ("device_ops", "idle_gaps")}

@@ -5,9 +5,10 @@ its idle time down to the serving phases of ``Scheduler.run``.
   python3 bench/scopes.py <trace dir or .xplane.pb> [--window <span>]
 
 prints one JSON object: ``scopes``, self seconds per module and scope, and
-``idle_by_span``, idle seconds per program span, over the first ``<span>``
-of the trace (by default ``bench.slice``, the benchmark's traced slice; the
-whole trace where there is none), averaged over devices.
+``idle_by_span``, idle seconds per program span (``bench/trace.py``'s
+``reduce``), over the first ``<span>`` of the trace (by default
+``bench.slice``, the benchmark's traced slice; the whole trace where there is
+none), averaged over devices.
 
 **Scopes.** ``jax.named_scope`` lands in the ``metadata.op_name`` of each
 compiled HLO instruction (a fusion carries its root's).  The profiler writes
@@ -20,13 +21,6 @@ it needs with ``google.protobuf`` and a schema of just those fields.  An
 op's scope is the path of the names in ``SCOPES`` along its ``op_name``
 (``linear``, ``linear/protect``, ``attention``), or ``OTHER`` outside them;
 its self time is as in ``bench/trace.py``.
-
-**Idle time.** Each idle gap of a device (no module or op event running) is
-split, by time, over the innermost program span that covers each part of it:
-``serve.*`` from the scheduler, ``bench.*`` from the harness.  The runtime's
-own spans nested in them (a device-to-host copy, a dispatch) are not
-program spans, so a gap under ``serve.readback`` counts there whatever the
-runtime was doing.  Time that no program span covers is ``NO_SPAN``.
 """
 from __future__ import annotations
 
@@ -43,8 +37,6 @@ from bench import trace as tr  # noqa: E402
 SCOPES = ("linear", "protect", "attention")
 OTHER = "(other)"
 NO_HLO = "(no hlo)"
-NO_SPAN = "(no program span)"
-PROGRAM_SPANS = ("serve.", "bench.")
 METADATA_PLANE = "/host:metadata"
 HLO_STAT = "Hlo Proto"
 
@@ -144,44 +136,10 @@ def scope_map(hlo: bytes) -> dict:
 
 
 # ---- the trace --------------------------------------------------------------
-def load(path: str):
-    """(devices, program spans, HLO protos) of a trace.  devices: {plane:
-    {"mods": [(start_ns, end_ns, program)], "ops": [(start_ns, end_ns,
-    program, op)]}}, with programs named as in the HLO protos
-    (``jit__chunk(12)``); on the CPU backend one device made of the host
-    threads' op events.  Program spans: (start_ns, end_ns, name) of every
-    host event named ``serve.*`` or ``bench.*``."""
-    from jax.profiler import ProfileData
-    raw = Path(path).read_bytes()
-    pd = ProfileData.from_serialized_xspace(raw)
-    devices, spans, cpu_ops = {}, [], []
-    on_tpu = any(p.name.startswith("/device:TPU:") for p in pd.planes)
-    for plane in pd.planes:
-        if plane.name.startswith("/device:TPU:"):
-            mods, ops = [], []
-            for line in plane.lines:
-                if line.name == "XLA Modules":
-                    mods.extend((e.start_ns, e.start_ns + e.duration_ns, e.name)
-                                for e in line.events)
-                elif line.name == "XLA Ops":
-                    ops.extend((e.start_ns, e.start_ns + e.duration_ns, "",
-                                tr.op_name(e.name)) for e in line.events)
-            devices[plane.name] = {"mods": mods, "ops": tr._attribute(ops, mods)}
-        elif plane.name.startswith("/host:CPU"):
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name.startswith(PROGRAM_SPANS):
-                        spans.append((e.start_ns, e.start_ns + e.duration_ns,
-                                      e.name))
-                        continue
-                    st = {} if on_tpu else tr._stats(e)
-                    if "hlo_op" in st:
-                        prog = f"{st.get('hlo_module', '')}({st.get('program_id', '')})"
-                        cpu_ops.append((e.start_ns, e.start_ns + e.duration_ns,
-                                        prog, tr.op_name(e.name)))
-    if not devices and cpu_ops:
-        devices["cpu"] = {"mods": [], "ops": sorted(cpu_ops)}
-    return devices, spans, hlo_protos(raw)
+def program_scopes(xspace: bytes) -> dict:
+    """{program: {instruction: scope}} of every program whose HLO a
+    serialized trace holds."""
+    return {p: scope_map(b) for p, b in hlo_protos(xspace).items()}
 
 
 def scope_times(devices: dict, maps: dict, lo: int, hi: int) -> dict:
@@ -205,37 +163,6 @@ def scope_times(devices: dict, maps: dict, lo: int, hi: int) -> dict:
     return {m: dict(v) for m, v in merged.items()}
 
 
-def idle_by_span(devices: dict, spans: list, lo: int, hi: int) -> dict:
-    """{program span name: seconds of device idle time under it} over [lo, hi]
-    ns, averaged over devices: each idle gap is split over the innermost
-    (shortest) program span covering each part of it.  The values sum to the
-    window less the busy time."""
-    n = max(len(devices), 1)
-    spans = [sp for sp in spans if sp[1] > lo and sp[0] < hi]
-    out = defaultdict(float)
-    for dev in devices.values():
-        busy = tr.union([(s, e) for s, e, _ in dev["mods"]]
-                        + [(s, e) for s, e, _, _ in dev["ops"]], lo, hi)
-        prev = lo
-        for s, e in busy + [[hi, hi]]:
-            if s > prev:
-                for name, t in _split(spans, prev, s):
-                    out[name] += t / 1e9 / n
-            prev = max(prev, e)
-    return dict(out)
-
-
-def _split(spans, a, b):
-    """(innermost span name, ns) for each part of [a, b] between the
-    boundaries of the spans that overlap it."""
-    over = [sp for sp in spans if sp[0] < b and sp[1] > a]
-    cuts = sorted({a, b} | {t for sp in over for t in sp[:2] if a < t < b})
-    for x, y in zip(cuts, cuts[1:]):
-        inner = [sp for sp in over if sp[0] <= x and sp[1] >= y]
-        yield (min(inner, key=lambda sp: sp[1] - sp[0])[2] if inner
-               else NO_SPAN), y - x
-
-
 def window(spans: list, devices: dict, name: str = "bench.slice"):
     """[lo, hi] ns of the first span called ``name``, else of the whole
     trace's device events."""
@@ -248,12 +175,13 @@ def window(spans: list, devices: dict, name: str = "bench.slice"):
 
 
 def summarize(path: str, span: str = "bench.slice") -> dict:
-    devices, spans, protos = load(path)
-    lo, hi = window(spans, devices, span)
-    return {"window_s": (hi - lo) / 1e9, "hlo_programs": len(protos),
-            "scopes": scope_times(devices, {p: scope_map(b) for p, b in protos.items()},
-                                  lo, hi),
-            "idle_by_span": idle_by_span(devices, spans, lo, hi)}
+    t = tr.load(path)
+    lo, hi = window(t.spans, t.devices, span)
+    maps = program_scopes(t.xspace)
+    return {"window_s": (hi - lo) / 1e9, "hlo_programs": len(maps),
+            "scopes": scope_times(t.devices, maps, lo, hi),
+            "idle_by_span": tr.reduce(t.devices, t.host, lo, hi,
+                                      spans=t.spans)["idle_by_span"]}
 
 
 def main(argv=None) -> int:

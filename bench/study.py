@@ -74,8 +74,9 @@ def main(argv=None) -> int:
             w["conf"]["protection"], policy=args.policy))
     import jax
     devices = jax.devices()[:w["chips"]]
-    if devices[0].platform != "tpu":
-        print("study: no TPU", file=sys.stderr)
+    if devices[0].platform != "tpu" or len(devices) < w["chips"]:
+        print(f"study: the cell needs {w['chips']} TPU chip(s)",
+              file=sys.stderr)
         return 2
     harness.use_compile_cache()
     run = run_module()

@@ -1,6 +1,6 @@
-"""Reduce a profiler trace (``.xplane.pb``) to device busy time, time and
-calls per compiled module, self time per operation, and idle gaps labelled
-by what the host was doing.
+"""Read a profiler trace (``.xplane.pb``) once, and reduce it to device
+busy time, time and calls per compiled module, self time per operation, and
+idle gaps labelled by what the host was doing.
 
 On the TPU each ``/device:TPU:<n>`` plane has an ``XLA Modules`` line (one
 event per run of an executable) and an ``XLA Ops`` line (one event per
@@ -11,12 +11,12 @@ self time (its length less that of the ops nested in it).  Op events are
 named by their HLO text; the name kept is the instruction's
 (``%fused_decode.3 = ...`` -> ``fused_decode.3``).  The host's activity is
 the events of its main thread's lines: the harness's ``TraceAnnotation``
-spans and the runtime's own.
+spans and the runtime's own.  Program spans are the host events named
+``serve.*`` (the scheduler's) or ``bench.*`` (the harness's), on any line.
 
-``from_cpu`` reads a trace of the CPU backend the same way (operations are
-the events with an ``hlo_op`` stat, and no module events exist, so a
-module's time is the union of its ops), so the reduction is tested without a
-chip.
+A trace of the CPU backend is read the same way (operations are the events
+with an ``hlo_op`` stat, and no module events exist, so a module's time is
+the union of its ops), so the reduction is tested without a chip.
 """
 from __future__ import annotations
 
@@ -24,10 +24,15 @@ import glob
 import os
 import re
 from collections import defaultdict
+from pathlib import Path
+from typing import NamedTuple
 
 MIN_GAP_NS = 10_000          # shorter idle gaps are summed, not labelled
 SHORT_GAPS = "(gaps under 10 us)"
 HOST_LINES = ("python", "main")
+PROGRAM_SPANS = ("serve.", "bench.")
+NO_SPAN = "(no program span)"
+TPU_PLANE = re.compile(r"/device:TPU:(\d+)")
 
 
 def module_name(raw: str) -> str:
@@ -57,63 +62,74 @@ def xplane_file(trace_dir: str) -> str:
     return max(files, key=os.path.getmtime)
 
 
-def _host_events(plane, marks: dict):
-    """Spans of the main thread's lines; spans named ``bench.*`` from any
-    line go into ``marks``."""
-    out = []
-    for line in plane.lines:
-        main = line.name.startswith(HOST_LINES)
-        for e in line.events:
-            span = (e.start_ns, e.start_ns + e.duration_ns, e.name)
-            if main:
-                out.append(span)
-            if e.name.startswith("bench.") and e.name not in marks:
-                marks[e.name] = span[:2]
-    return out
+class Trace(NamedTuple):
+    """What ``load`` reads from a trace.
+
+    devices: {plane: {"mods": [(start_ns, end_ns, program)], "ops":
+    [(start_ns, end_ns, program, op)]}}, programs named as the profiler names
+    them (``jit__chunk(12)``); host: (start_ns, end_ns, name) of the main
+    thread's events; spans: the program spans; marks: {name: (start_ns,
+    end_ns)} of the first program span of each ``bench.*`` name; xspace: the
+    serialized trace, for its HLO protos (``bench/scopes.py``)."""
+    devices: dict
+    host: list
+    spans: list
+    marks: dict
+    xspace: bytes
 
 
-def load(path: str):
-    """(devices, host spans, marks) of a TPU trace.  devices: {plane:
-    {"mods": [(start_ns, end_ns, module)], "ops": [(start_ns, end_ns, module,
-    op)]}}; a host span is (start_ns, end_ns, name); marks: {name: (start_ns,
-    end_ns)} of the harness's ``bench.*`` spans."""
+def load(path: str, device_ids=None) -> Trace:
+    """Read a trace once.  With ``device_ids``, only the TPU planes of those
+    device ids are kept, so that chips a cell does not use add nothing to
+    the means over its chips."""
     from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
-    devices, host, marks = {}, [], {}
+    raw = Path(path).read_bytes()
+    pd = ProfileData.from_serialized_xspace(raw)
+    devices, host, spans, marks, cpu_ops = {}, [], [], {}, []
+    on_tpu = any(TPU_PLANE.match(p.name) for p in pd.planes)
     for plane in pd.planes:
-        if plane.name.startswith("/device:TPU:"):
+        if TPU_PLANE.match(plane.name):
             mods, ops = [], []
             for line in plane.lines:
                 if line.name == "XLA Modules":
-                    mods.extend((e.start_ns, e.start_ns + e.duration_ns,
-                                 module_name(e.name)) for e in line.events)
+                    mods.extend((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                                for e in line.events)
                 elif line.name == "XLA Ops":
                     ops.extend((e.start_ns, e.start_ns + e.duration_ns, "",
                                 op_name(e.name)) for e in line.events)
             devices[plane.name] = {"mods": mods, "ops": _attribute(ops, mods)}
         elif plane.name.startswith("/host:CPU"):
-            host.extend(_host_events(plane, marks))
-    return devices, host, marks
+            for line in plane.lines:
+                main = line.name.startswith(HOST_LINES)
+                for e in line.events:
+                    span = (e.start_ns, e.start_ns + e.duration_ns, e.name)
+                    if main:
+                        host.append(span)
+                    if e.name.startswith(PROGRAM_SPANS):
+                        spans.append(span)
+                        if e.name.startswith("bench."):
+                            marks.setdefault(e.name, span[:2])
+                        continue
+                    st = {} if on_tpu else _stats(e)
+                    if "hlo_op" in st:
+                        prog = f"{st.get('hlo_module', '')}({st.get('program_id', '')})"
+                        cpu_ops.append((span[0], span[1], prog, op_name(e.name)))
+    if not devices and cpu_ops:
+        devices["cpu"] = {"mods": [], "ops": sorted(cpu_ops)}
+    if device_ids is not None:
+        devices = on_devices(devices, device_ids)
+    return Trace(devices, host, spans, marks, raw)
 
 
-def from_cpu(path: str):
-    """The same as ``load`` for a trace of the CPU backend: one 'device'
-    made of the host threads' XLA operation events."""
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
-    ops, host, marks = [], [], {}
-    for plane in pd.planes:
-        if not plane.name.startswith("/host:CPU"):
-            continue
-        host.extend(_host_events(plane, marks))
-        for line in plane.lines:
-            for e in line.events:
-                st = _stats(e)
-                if "hlo_op" in st:
-                    ops.append((e.start_ns, e.start_ns + e.duration_ns,
-                                module_name(str(st.get("hlo_module", ""))),
-                                op_name(e.name)))
-    return {"cpu": {"mods": [], "ops": sorted(ops)}}, host, marks
+def on_devices(devices: dict, device_ids) -> dict:
+    """``devices`` without the TPU planes of other device ids than
+    ``device_ids`` (the plane ``/device:TPU:<n>`` is device id n)."""
+    out = {}
+    for name, dev in devices.items():
+        tpu = TPU_PLANE.match(name)
+        if not tpu or int(tpu.group(1)) in device_ids:
+            out[name] = dev
+    return out
 
 
 def _attribute(ops, mods):
@@ -189,14 +205,23 @@ class _HostIndex:
         return best[2] if best else "(no host span)"
 
 
-def reduce(devices: dict, host: list, lo: int, hi: int, top: int = 10):
+def reduce(devices: dict, host: list, lo: int, hi: int, top: int = 10,
+           spans: list = ()):
     """Over the window [lo, hi] ns, averaged over devices: busy seconds,
     seconds and calls (a call cut by the window counts by its share) per
-    module, self seconds, whole seconds and calls per op (keyed
-    ``module/op``), the ``top`` ops by self time and the ``top`` host
-    activities under idle gaps."""
+    module (named by ``module_name``), self seconds, whole seconds and calls
+    per op (keyed ``module/op``), the ``top`` ops by self time, the ``top``
+    host activities under idle gaps, and ``idle_by_span``: each idle gap
+    split, by time, over the innermost (shortest) program span of ``spans``
+    covering each part of it (``NO_SPAN`` where none does), so that its
+    values sum to the window less the busy time.  The runtime's own spans
+    nested in a program span (a device-to-host copy, a dispatch) are not
+    program spans: a gap under ``serve.readback`` counts there whatever the
+    runtime was doing."""
     n = max(len(devices), 1)
     index = _HostIndex([h for h in host if h[1] >= lo and h[0] <= hi])
+    spans = [sp for sp in spans if sp[1] > lo and sp[0] < hi]
+    by_span = defaultdict(float)
     busy = 0.0
     modules, calls = defaultdict(float), defaultdict(float)
     ops, op_calls, gaps = defaultdict(float), defaultdict(float), defaultdict(float)
@@ -208,22 +233,26 @@ def reduce(devices: dict, host: list, lo: int, hi: int, top: int = 10):
         busy += _covered(iv)
         by_mod = defaultdict(list)
         for s, e, mod in mods:
+            mod = module_name(mod)
             if _overlap(s, e, lo, hi):
                 by_mod[mod].append((s, e))
                 calls[mod] += _overlap(s, e, lo, hi) / max(e - s, 1) / n
         if not mods:                         # CPU: modules from their ops
             for s, e, mod, _ in evs:
-                by_mod[mod].append((s, e))
+                by_mod[module_name(mod)].append((s, e))
         for mod, v in by_mod.items():
             modules[mod] += _covered(union(v, lo, hi)) / 1e9 / n
         for s, e, mod, name, own in self_times(evs):
             if _overlap(s, e, lo, hi):
-                key = f"{mod}/{name}"
+                key = f"{module_name(mod)}/{name}"
                 ops[key] += own * _overlap(s, e, lo, hi) / max(e - s, 1) / 1e9 / n
                 op_total[key] += _overlap(s, e, lo, hi) / 1e9 / n
                 op_calls[key] += 1 / n
         prev = lo
         for s, e in iv + [[hi, hi]]:
+            if s > prev:
+                for name, t in _split(spans, prev, s):
+                    by_span[name] += t / 1e9 / n
             if s - prev >= MIN_GAP_NS:
                 gaps[index.label((s + prev) // 2)] += (s - prev) / 1e9 / n
             elif s > prev:
@@ -241,4 +270,16 @@ def reduce(devices: dict, host: list, lo: int, hi: int, top: int = 10):
                        sorted(ops.items(), key=lambda kv: -kv[1])[:top]],
         "idle_gaps": [[k, v] for k, v in
                       sorted(gaps.items(), key=lambda kv: -kv[1])[:top]],
+        "idle_by_span": dict(by_span),
     }
+
+
+def _split(spans, a, b):
+    """(innermost span name, ns) for each part of [a, b] between the
+    boundaries of the spans that overlap it."""
+    over = [sp for sp in spans if sp[0] < b and sp[1] > a]
+    cuts = sorted({a, b} | {t for sp in over for t in sp[:2] if a < t < b})
+    for x, y in zip(cuts, cuts[1:]):
+        inner = [sp for sp in over if sp[0] <= x and sp[1] >= y]
+        yield (min(inner, key=lambda sp: sp[1] - sp[0])[2] if inner
+               else NO_SPAN), y - x

@@ -78,10 +78,13 @@ def _layout(path, cfg):
     return "/".join(keys), NO_LAYER
 
 
-def make_params(model, seed: int):
+def make_params(model, seed: int, shardings=None):
     """The program's parameter tree, drawn on the device in one jitted call.
     The seed is an argument of that call, so every seed runs one compiled
-    program."""
+    program.  ``shardings`` (a tree of shardings like the parameters) places
+    each leaf as it is drawn, so each device draws only its own shard; the
+    values are those of the unsharded draw, because threefry is
+    partitionable (JAX's default, which ``repro.core.faults`` sets too)."""
     cfg = model.cfg
     spec = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(spec)
@@ -104,4 +107,4 @@ def make_params(model, seed: int):
                                  dtype=sd.dtype, kind=_kind(name, shape)))
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    return jax.jit(build)(jnp.int32(jax_seed(seed, 1)))
+    return jax.jit(build, out_shardings=shardings)(jnp.int32(jax_seed(seed, 1)))

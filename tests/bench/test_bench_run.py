@@ -6,9 +6,10 @@ reference at int4, the precision below the int8 datapath that crt3 states)
 fails the crt3 cell's limit through the harness's own comparison, on the
 sample a run checked (``bench/study.py``).
 
-The other faults a cell can have do not exist in these one-chip serving
-cells: no batch mean is taken (no training), and no exchange between chips
-runs."""
+No batch mean is taken in a serving cell (no training), so that fault does
+not exist here.  These cells run on one chip; the exchange between chips
+left out is caught in a cell sharded over four devices
+(``test_bench_mesh.py``)."""
 import time
 
 import jax
@@ -51,8 +52,8 @@ def test_sound_run_is_correct(run, root):
 def test_token_altered_where_produced_is_caught(run, root, monkeypatch):
     build = harness.build_system
 
-    def broken(conf, seed):
-        model, params, sched = build(conf, seed)
+    def broken(conf, seed, devices):
+        model, params, sched = build(conf, seed, devices)
         chunk = sched._chunk
 
         def altered(*a):

@@ -18,7 +18,7 @@ def served(tmp_path_factory):
     conf = {"model": tiny_cells.MODEL, "scheduler": tiny_cells.SCHED,
             "protection": tiny_cells.CRT3,
             "run": {"param_dtype": "bfloat16", "compute_dtype": "bfloat16"}}
-    _, _, sched = harness.build_system(conf, 5)
+    _, _, sched = harness.build_system(conf, 5, jax.devices()[:1])
 
     def reqs(base):
         return [Request(base + r, [1 + i] * (4 + 5 * i), max_new_tokens=5)
@@ -71,18 +71,18 @@ def test_stats_count_every_phase(served):
 
 def test_scopes_split_the_chunk(served):
     _, path = served
-    devices, spans, protos = scopes.load(path)
-    assert any(p.startswith("jit__chunk(") for p in protos)
-    lo, hi = scopes.window(spans, devices)
-    maps = {p: scopes.scope_map(b) for p, b in protos.items()}
-    got = scopes.scope_times(devices, maps, lo, hi)
+    t = tr.load(path)
+    maps = scopes.program_scopes(t.xspace)
+    assert any(p.startswith("jit__chunk(") for p in maps)
+    lo, hi = scopes.window(t.spans, t.devices)
+    got = scopes.scope_times(t.devices, maps, lo, hi)
     chunk, prefill = got["_chunk"], got["_prefill_one"]
     assert chunk["linear/protect"] > 0 and chunk["attention"] > 0
     assert prefill["linear/protect"] > 0 and prefill["attention"] > 0
     assert scopes.NO_HLO not in chunk
-    idle = scopes.idle_by_span(devices, spans, lo, hi)
+    idle = tr.reduce(t.devices, t.host, lo, hi, spans=t.spans)["idle_by_span"]
     assert idle.get("serve.readback", 0) > 0
-    assert scopes.NO_SPAN not in idle      # the slice covers the whole run
+    assert tr.NO_SPAN not in idle          # the slice covers the whole run
 
 
 def test_scope_of():
@@ -127,8 +127,8 @@ def test_idle_by_span_hand_made():
                       "ops": []}}
     spans = [(0, 100 * us, "bench.slice"), (5 * us, 40 * us, "serve.readback"),
              (40 * us, 65 * us, "serve.admit"), (45 * us, 50 * us, "serve.prefill")]
-    got = scopes.idle_by_span(devices, spans, 0, 110 * us)
+    got = tr.reduce(devices, [], 0, 110 * us, spans=spans)["idle_by_span"]
     assert got == pytest.approx({"serve.readback": 30e-6, "serve.admit": 15e-6,
                                  "serve.prefill": 5e-6, "bench.slice": 30e-6,
-                                 scopes.NO_SPAN: 10e-6}, abs=1e-15)
+                                 tr.NO_SPAN: 10e-6}, abs=1e-15)
     assert sum(got.values()) == pytest.approx((110 - 20) * 1e-6)

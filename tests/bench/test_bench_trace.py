@@ -4,6 +4,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 import tiny_cells  # noqa: F401
 from bench import trace as tr
@@ -77,10 +78,28 @@ def test_cpu_trace(tmp_path):
             f(x).block_until_ready()
         time.sleep(0.02)
     jax.profiler.stop_trace()
-    devs, host, marks = tr.from_cpu(tr.xplane_file(str(tmp_path)))
-    r = tr.reduce(devs, host, *marks["bench.slice"])
+    t = tr.load(tr.xplane_file(str(tmp_path)))
+    r = tr.reduce(t.devices, t.host, *t.marks["bench.slice"])
     assert 0 < r["busy_s"] < r["window_s"]
     assert r["modules"]["_lambda"] > 0
     assert sum(n for k, n in r["op_calls"].items() if "dot" in k) >= 6
     # the sleep at the end of the span is idle time under the harness's span
     assert dict(r["idle_gaps"]).get("bench.slice", 0) >= 0.015
+
+
+def test_planes_of_other_chips_are_left_out():
+    """A chip that the cell does not use adds nothing to the means over the
+    cell's chips: a trace with one more, idle, plane reads as without it."""
+    us = 1000
+    used = {"/device:TPU:0": {"mods": [(0, 30 * us, "jit__chunk(3)")],
+                              "ops": [(0, 30 * us, "jit__chunk(3)", "fusion.2")]}}
+    host = [(0, 100 * us, "bench.slice")]
+    spans = [(0, 100 * us, "bench.slice")]
+    alone = tr.reduce(used, host, 0, 100 * us, spans=spans)
+    idle_chip = dict(used, **{"/device:TPU:1": {"mods": [], "ops": []}})
+    assert tr.reduce(tr.on_devices(idle_chip, {0}), host, 0, 100 * us,
+                     spans=spans) == alone
+    # read over both planes, every time would be halved
+    both = tr.reduce(idle_chip, host, 0, 100 * us, spans=spans)
+    assert both["modules"]["_chunk"] == pytest.approx(
+        alone["modules"]["_chunk"] / 2)
