@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from repro.core import faults
 from repro.core import quantization as Q
 from repro.ft.policy import ProtectionPolicy
+from repro.parallel import ctx as pctx
 
 BACKENDS = ("reference", "fused", "pallas")
 
@@ -56,7 +57,7 @@ def protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
                    layer_protected: bool = True,
                    backend: str = "reference",
                    t: int | None = None,
-                   dyn=None) -> jax.Array:
+                   dyn=None, split: str | None = None) -> jax.Array:
     """Fault-tolerant linear: float in/out, faulty quantized DLA inside.
 
     Args:
@@ -83,6 +84,12 @@ def protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
         by the reference and fused backends (the fused kernel takes
         ``q_scale`` as a scalar operand and folds ``ib_th``/``nb_th`` into
         the flip-word draws).
+      split: how the serving layout divides ``w`` over the tensor-parallel
+        axis — "column" (its N), "row" (its K) or None — which the fused
+        backend needs under a mesh (``parallel.ctx``) whose axis holds more
+        than one device: there it runs the kernel in a ``shard_map`` with
+        its exchange (``kernels/fused_decode/ops.py``).  The reference
+        backend is partitioned by GSPMD and ignores it.
     Returns (..., N) float32.
     """
     if backend == "reference":
@@ -92,7 +99,7 @@ def protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
         from repro.kernels.fused_decode.ops import fused_protect_linear
         return fused_protect_linear(key, x, w, policy, important,
                                     layer_protected=layer_protected,
-                                    dyn=dyn)
+                                    dyn=dyn, tp=_tp(split))
     if getattr(key, "ndim", 1) == 2:
         raise ValueError("per-row key batches are only supported by "
                          "backend='reference' or backend='fused'")
@@ -106,6 +113,15 @@ def protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
                                layer_protected=layer_protected, t=t)
     raise ValueError(f"unknown backend {backend!r}; expected one of "
                      f"{BACKENDS}")
+
+
+def _tp(split: str | None):
+    """``(mesh, axis, split)`` of the mesh context whose tensor-parallel
+    axis holds more than one device; None without one."""
+    c = pctx.get_ctx()
+    if c is None or c.tp_size == 1:
+        return None
+    return c.mesh, c.tp, split
 
 
 # ------------------------------------------------------ straight-through ----
@@ -145,7 +161,7 @@ def protect_linear_ste(key: jax.Array, x: jax.Array, w: jax.Array,
     non-differentiable quantize/flip/truncate chain is treated as identity,
     so gradients flow and the network learns to place its decision margins
     where bit flips cannot reach them.  ``kw`` is forwarded verbatim
-    (``layer_protected`` / ``backend`` / ``t`` / ``dyn``).
+    (``layer_protected`` / ``backend`` / ``t`` / ``dyn`` / ``split``).
     """
     y = protect_linear(key, jax.lax.stop_gradient(x),
                        jax.lax.stop_gradient(w), policy, important, **kw)

@@ -34,6 +34,16 @@ decode rows run the kernel, while 512-row prefill of the wide projections
 and their per-row weight faults (an (M, bk, N) int32 flip block) run the
 reference.  ``tests/test_tpu_compile.py`` checks the plan against the v5e
 compiler.
+
+Under a tensor-parallel mesh (``ops.py``) each chip holds a slice of the
+weight's columns or rows, and the epilogue needs the whole row's absmax or
+the whole sum over K.  There the kernel runs in an accumulate-only ``mode``
+("acc": the saturated accumulator and its absmax; "partial": the
+unsaturated partial sum), the maxima or the sums cross the chips, and the
+one ``epilogue`` function that ``_finish`` calls finishes the datapath on
+them.  There the int32 output block takes the place of the flip words and
+the int8 output, and no epilogue temporaries are held, so a shape the plan
+admits also fits in these modes.
 """
 from __future__ import annotations
 
@@ -88,19 +98,63 @@ def _trunc(acc, t, out_bits):
     return jnp.clip((acc + half) >> t, -qmax - 1, qmax)
 
 
+def saturate(acc, acc_bits: int = ACC_BITS):
+    """The accumulator clipped to ``acc_bits``-wide two's complement."""
+    return jnp.clip(acc, -(1 << (acc_bits - 1)), (1 << (acc_bits - 1)) - 1)
+
+
+def absmax(acc, per_row: bool):
+    """(M, 1) row maxima of ``|acc|``, or its one maximum."""
+    if per_row:
+        return jnp.max(jnp.abs(acc), axis=1, keepdims=True)
+    return jnp.max(jnp.abs(acc))
+
+
+def epilogue(acc, amax, q_scale, oflips, *, dflips=None, imp=None,
+             acc_d=None, bits: int = 8, acc_bits: int = ACC_BITS,
+             out_bits: int = OUT_BITS):
+    """The integer datapath after accumulation, written once for the kernel
+    and for the sharded call (``ops.py``): the truncation LSB ``t`` from the
+    saturated accumulator's absmax ``amax``, the rounded 8-bit window, the
+    XOR of the output flip words, the DPPU select of important columns
+    (``dflips``/``imp``; its unsaturated accumulator ``acc_d``, by default
+    ``acc`` itself) and the sign extension.  Returns the int8 outputs and
+    ``t``.
+
+    ``q_scale`` is (1, 1); it, the flip words, ``imp`` and ``acc_d`` may be
+    arrays or the kernel's refs, each read where it is used."""
+    # t from the accumulator's integer bit-length: popcount over
+    # threshold compares — bit-identical to Q.choose_trunc_lsb.
+    a = jnp.maximum(amax, 1)
+    need = jnp.zeros_like(a)
+    for b in range(acc_bits):
+        need += (a >= (1 << b)).astype(jnp.int32)
+    t = jnp.maximum(need - (out_bits - 1), 0)
+    t = jnp.clip(t, q_scale[0, 0], acc_bits - out_bits)
+
+    mask_all = (1 << bits) - 1
+    uy = (_trunc(acc, t, out_bits) & mask_all) ^ oflips[...]
+    if dflips is not None:
+        acc_d = acc if acc_d is None else saturate(acc_d[...], acc_bits)
+        ud = (_trunc(acc_d, t, out_bits) & mask_all) ^ dflips[...]
+        uy = jnp.where(imp[...] != 0, ud, uy)
+    return _sign_extend(uy, bits).astype(jnp.int8), t
+
+
 def _kernel(*refs, nk: int, per_row: bool, dppu_src: str, perrow_wf: bool,
-            bits: int, acc_bits: int, out_bits: int):
+            bits: int, acc_bits: int, out_bits: int, mode: str):
     it = iter(refs)
     x_ref = next(it)
     w_ref = next(it)
     wcl_ref = next(it) if dppu_src == "wcl" else None
     wflips_ref = next(it) if perrow_wf else None
-    oflip_ref = next(it)
-    dflip_ref = next(it) if dppu_src != "none" else None
-    imp_ref = next(it) if dppu_src != "none" else None
-    qs_ref = next(it)
+    fused = mode == "fused"
+    oflip_ref = next(it) if fused else None
+    dflip_ref = next(it) if fused and dppu_src != "none" else None
+    imp_ref = next(it) if fused and dppu_src != "none" else None
+    qs_ref = next(it) if fused else None
     o_ref = next(it)
-    t_ref = next(it)
+    t_ref = next(it) if mode != "partial" else None
     acc_ref = next(it)
     accd_ref = next(it) if dppu_src in ("w", "wcl") else None
 
@@ -134,42 +188,34 @@ def _kernel(*refs, nk: int, per_row: bool, dppu_src: str, perrow_wf: bool,
 
     @pl.when(pl.program_id(0) == nk - 1)
     def _finish():
-        lo = -(1 << (acc_bits - 1))
-        hi = (1 << (acc_bits - 1)) - 1
-        acc = jnp.clip(acc_ref[...], lo, hi)
+        if mode == "partial":
+            o_ref[...] = acc_ref[...]
+            return
+        acc = saturate(acc_ref[...], acc_bits)
         m = acc.shape[0]
-        if per_row:
-            absmax = jnp.max(jnp.abs(acc), axis=1, keepdims=True)  # (M, 1)
-        else:
-            absmax = jnp.max(jnp.abs(acc))
-        # t from the accumulator's integer bit-length: popcount over
-        # threshold compares — bit-identical to Q.choose_trunc_lsb.
-        a = jnp.maximum(absmax, 1)
-        need = jnp.zeros_like(a)
-        for b in range(acc_bits):
-            need += (a >= (1 << b)).astype(jnp.int32)
-        t = jnp.maximum(need - (out_bits - 1), 0)
-        t = jnp.clip(t, qs_ref[0, 0], acc_bits - out_bits)
-
-        mask_all = (1 << bits) - 1
-        uy = (_trunc(acc, t, out_bits) & mask_all) ^ oflip_ref[...]
+        amax = absmax(acc, per_row)
+        if mode == "acc":
+            o_ref[...] = acc
+            t_ref[...] = jnp.broadcast_to(amax, (m, 1))
+            return
+        kw = {}
         if dppu_src != "none":
-            acc_d = acc if dppu_src == "reuse" else jnp.clip(
-                accd_ref[...], lo, hi)
-            ud = (_trunc(acc_d, t, out_bits) & mask_all) ^ dflip_ref[...]
-            uy = jnp.where(imp_ref[...] != 0, ud, uy)
-        o_ref[...] = _sign_extend(uy, bits).astype(jnp.int8)
+            kw = dict(dflips=dflip_ref, imp=imp_ref, acc_d=accd_ref)
+        y, t = epilogue(acc, amax, qs_ref, oflip_ref, bits=bits,
+                        acc_bits=acc_bits, out_bits=out_bits, **kw)
+        o_ref[...] = y
         t_ref[...] = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (m, 1))
 
 
 @functools.partial(jax.jit, static_argnames=(
     "per_row", "dppu_src", "perrow_wf", "bk", "bits", "acc_bits", "out_bits",
-    "interpret"))
-def fused_decode(xq, wq, oflips, q_scale, *, wq_clean=None, wflips=None,
-                 dflips=None, imp=None, per_row: bool = False,
+    "mode", "interpret"))
+def fused_decode(xq, wq, oflips=None, q_scale=None, *, wq_clean=None,
+                 wflips=None, dflips=None, imp=None, per_row: bool = False,
                  dppu_src: str = "none", perrow_wf: bool = False,
                  bk: int = 128, bits: int = 8, acc_bits: int = ACC_BITS,
-                 out_bits: int = OUT_BITS, interpret: bool | None = None):
+                 out_bits: int = OUT_BITS, mode: str = "fused",
+                 interpret: bool | None = None):
     """One fused decode step.
 
     Args:
@@ -184,14 +230,24 @@ def fused_decode(xq, wq, oflips, q_scale, *, wq_clean=None, wflips=None,
       dppu_src: "none" | "reuse" (clean acc == faulty acc: no weight
         faults) | "w" (recompute from `wq`, which is clean in per-row
         weight-fault mode) | "wcl" (recompute from `wq_clean`).
+      mode: "fused" (the whole datapath) or, for a call whose epilogue
+        needs columns or rows that other chips hold (``ops.py``), the
+        accumulation alone, with no flip-word or ``q_scale`` operand and
+        ``dppu_src`` "none" or "reuse": "acc" returns the saturated int32
+        accumulator and its (M, 1) absmax (per row, or the block's maximum
+        in every row), "partial" the unsaturated int32 accumulator.
     Returns:
       (y, t): (M, N) int8 outputs and (M, 1) int32 truncation LSBs
-      (all rows equal when per_row=False).
+      (all rows equal when per_row=False); for mode "acc" (acc, absmax),
+      for "partial" the accumulator.
     """
     M, K = xq.shape
     _, N = wq.shape
     assert M % 8 == 0 and N % 128 == 0 and K % bk == 0, (
         "fused_decode operands must be tile-aligned (pad in ops.py)")
+    assert mode == "fused" or (dppu_src in ("none", "reuse")
+                               and not perrow_wf), mode
+    fused = mode == "fused"
     nk = K // bk
     grid = (nk,)
 
@@ -206,31 +262,38 @@ def fused_decode(xq, wq, oflips, q_scale, *, wq_clean=None, wflips=None,
     if perrow_wf:
         operands.append(wflips)
         in_specs.append(pl.BlockSpec((M, bk, N), lambda k: (0, k, 0)))
-    operands.append(oflips)
-    in_specs.append(pl.BlockSpec((M, N), lambda k: (0, 0)))
-    if dppu_src != "none":
-        operands.extend([dflips, imp])
-        in_specs.extend([pl.BlockSpec((M, N), lambda k: (0, 0)),
-                         pl.BlockSpec((1, N), lambda k: (0, 0))])
-    operands.append(q_scale)
-    in_specs.append(pl.BlockSpec((1, 1), lambda k: (0, 0)))
+    if fused:
+        operands.append(oflips)
+        in_specs.append(pl.BlockSpec((M, N), lambda k: (0, 0)))
+        if dppu_src != "none":
+            operands.extend([dflips, imp])
+            in_specs.extend([pl.BlockSpec((M, N), lambda k: (0, 0)),
+                             pl.BlockSpec((1, N), lambda k: (0, 0))])
+        operands.append(q_scale)
+        in_specs.append(pl.BlockSpec((1, 1), lambda k: (0, 0)))
+
+    out_dtype = jnp.int8 if fused else jnp.int32
+    out_specs = [pl.BlockSpec((M, N), lambda k: (0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((M, N), out_dtype)]
+    if mode != "partial":
+        out_specs.append(pl.BlockSpec((M, 1), lambda k: (0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((M, 1), jnp.int32))
 
     scratch = [pltpu.VMEM((M, N), jnp.int32)]
     if dppu_src in ("w", "wcl"):
         scratch.append(pltpu.VMEM((M, N), jnp.int32))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, nk=nk, per_row=per_row, dppu_src=dppu_src,
                           perrow_wf=perrow_wf, bits=bits, acc_bits=acc_bits,
-                          out_bits=out_bits),
+                          out_bits=out_bits, mode=mode),
         grid=grid,
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((M, N), lambda k: (0, 0)),
-                   pl.BlockSpec((M, 1), lambda k: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((M, N), jnp.int8),
-                   jax.ShapeDtypeStruct((M, 1), jnp.int32)],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=resolve_interpret(interpret),
         name="fused_decode",
     )(*operands)
+    return out[0] if mode == "partial" else out

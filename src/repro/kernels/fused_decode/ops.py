@@ -18,6 +18,35 @@ weight faults, and with traced ``dyn`` knob overrides.  That contract is
 also what lets a call whose static shapes exceed the kernel's VMEM plan
 (``kernel.vmem_bytes`` over ``kernel.VMEM_LIMIT``: prefill rows, per-row
 weight faults at model widths) run the reference datapath instead.
+
+**Under a tensor-parallel mesh** (``tp``: a mesh whose ``model`` axis holds
+more than one device, as the scheduler serves under) the contract covers
+the sharded call too: the tokens equal the one-device run's bit for bit.
+The kernel cannot be partitioned by GSPMD, so only the kernel and its
+exchange run in a ``shard_map`` over the mesh; quantization and the fault
+draws stay outside, where GSPMD partitions them.  The flip words are drawn
+under the projection's own sharding (columns over ``model`` for a
+column-parallel weight, replicated for a row-parallel one), so that with
+``jax_threefry_partitionable`` each chip draws exactly its slice of the
+one-device draw, keyed by global row and column.  Inside:
+
+  * column-parallel (N split: ``wq``/``wk``/``wv``/``wi``/``wg``): each
+    chip accumulates its columns; the per-row absmax is a ``pmax`` over
+    ``model``, from which every chip finishes its own columns;
+  * row-parallel (K split: ``wo``): each chip's unsaturated partial int32
+    sum (a 4736-deep partial of int8 products stays far inside int32) is a
+    ``psum`` over ``model``, then saturated and finished on the whole row,
+    replicated.
+
+Both finish through ``kernel.epilogue``, the function the kernel's own
+epilogue calls.  The ``pmax``/``psum`` sit under the named scope
+``exchange``.  Serving's variants take this path: per-row or global keys,
+DPPU ``none`` or ``reuse``.  Under such a mesh every other call (weight
+faults, a DPPU recompute from weights, a weight that is not divided or
+whose divided dimension does not split evenly, a VMEM plan the kernel
+refuses) runs the bitwise-equal reference datapath, which GSPMD
+partitions: outside a ``shard_map`` the kernel does not lower for more
+than one device.
 """
 from __future__ import annotations
 
@@ -25,10 +54,12 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import faults
 from repro.core import quantization as Q
-from repro.kernels.fused_decode.kernel import (VMEM_LIMIT, fused_decode,
+from repro.kernels.fused_decode.kernel import (VMEM_LIMIT, absmax, epilogue,
+                                               fused_decode, saturate,
                                                vmem_bytes)
 
 
@@ -51,11 +82,22 @@ def kernel_fits(m: int, n: int, *, dppu_src: str = "none",
                       perrow_wf=perrow_wf) <= VMEM_LIMIT
 
 
-@partial(jax.jit, static_argnames=("layer_protected", "interpret"))
+def _shardable(tp, k: int, n: int, policy, dppu_src: str) -> bool:
+    """Whether a call under ``tp`` runs the kernel in a ``shard_map``: a
+    weight divided evenly over the axis, no weight faults, and a DPPU
+    source that needs no second accumulator."""
+    mesh, axis, split = tp
+    dim = {"column": n, "row": k}.get(split)
+    return (dim is not None and dim % mesh.shape[axis] == 0
+            and not policy.weight_faults and dppu_src in ("none", "reuse"))
+
+
+@partial(jax.jit, static_argnames=("layer_protected", "interpret", "tp"))
 def fused_protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
                          policy, important: jax.Array | None = None, *,
                          layer_protected: bool = True, dyn=None,
-                         interpret: bool | None = None) -> jax.Array:
+                         interpret: bool | None = None,
+                         tp=None) -> jax.Array:
     """Fault-tolerant linear on the fused kernel: float in/out.
 
     Accepts everything ``protect_linear`` does — a single key or an (M, 2)
@@ -63,6 +105,10 @@ def fused_protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
     per-row), ``important`` masks, ``layer_protected`` and traced ``dyn``
     overrides — and matches the reference backend bit-for-bit.  A call the
     kernel's VMEM plan cannot hold (``kernel_fits``) runs the reference.
+
+    ``tp``: ``(mesh, axis, split)`` under a mesh whose ``axis`` holds more
+    than one device, ``split`` how the weight is divided over it: "column"
+    (its N), "row" (its K) or None; see the module docstring.
     """
     orig_shape = x.shape
     x2 = x.reshape(-1, orig_shape[-1])
@@ -75,7 +121,10 @@ def fused_protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
                     else "reuse")             # no weight faults: acc reused
     else:
         dppu_src = "none"
-    if not kernel_fits(m, n, dppu_src=dppu_src, perrow_wf=perrow_wf):
+    sharded = tp is not None and _shardable(tp, w.shape[0], n, policy,
+                                            dppu_src)
+    if (not kernel_fits(m, n, dppu_src=dppu_src, perrow_wf=perrow_wf)
+            or (tp is not None and not sharded)):
         from repro.ft.api import _protect_reference
         return _protect_reference(key, x, w, policy, important,
                                   layer_protected, dyn)
@@ -114,6 +163,8 @@ def fused_protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
             k, (n,), policy.ber, Q.OUT_BITS, pmask))(ka)
     else:
         oflips = faults.flip_word(ka, (m, n), policy.ber, Q.OUT_BITS, pmask)
+    if sharded:
+        oflips = _like_output(oflips, tp)
 
     # DPPU recompute flip words
     dflips, imp_arr = None, None
@@ -127,7 +178,29 @@ def fused_protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
             dflips = faults.flip_word(kd, (m, n), policy.ber, Q.OUT_BITS,
                                       dmask)
         imp_arr = important.astype(jnp.int32)
+        if sharded:
+            dflips = _like_output(dflips, tp)
+            imp_arr = _like_output(imp_arr.reshape(1, n), tp)
 
+    if sharded:
+        yq, t = _sharded(xq.astype(jnp.int8), wq.astype(jnp.int8), oflips,
+                         q_scale, dflips, imp_arr, per_row=per_row, tp=tp,
+                         interpret=interpret)
+    else:
+        yq, t = _one_device(xq, wq_k, oflips, q_scale, wq_clean, wflips,
+                            dflips, imp_arr, per_row=per_row,
+                            dppu_src=dppu_src, perrow_wf=perrow_wf,
+                            interpret=interpret)
+    scale = sx * sw * (2.0 ** t.astype(jnp.float32))
+    y = yq.astype(jnp.float32) * scale
+    return y.reshape(*orig_shape[:-1], n)
+
+
+def _one_device(xq, wq_k, oflips, q_scale, wq_clean, wflips, dflips,
+                imp_arr, *, per_row, dppu_src, perrow_wf, interpret):
+    """The kernel on whole operands: (M, N) int8-valued int32 outputs and
+    ``t``."""
+    m, n = xq.shape[0], wq_k.shape[1]
     # tile-align (zero pads are exact for the integer datapath; padded rows
     # have absmax 0 so they never move a per-row or global t)
     xq8 = _pad_to(xq.astype(jnp.int8), (8, 128))
@@ -147,7 +220,61 @@ def fused_protect_linear(key: jax.Array, x: jax.Array, w: jax.Array,
     yq8, tcol = fused_decode(xq8, wq8, _pad_to(oflips, (8, 128)), qs,
                              **kw_args)
     yq = yq8[:m, :n].astype(jnp.int32)
-    t = tcol[:m] if per_row else tcol[0, 0]
-    scale = sx * sw * (2.0 ** t.astype(jnp.float32))
-    y = yq.astype(jnp.float32) * scale
-    return y.reshape(*orig_shape[:-1], n)
+    return yq, (tcol[:m] if per_row else tcol[0, 0])
+
+
+def _specs(tp):
+    """(x, w, output) partition specs of a call divided as ``tp`` says."""
+    _, axis, split = tp
+    if split == "column":
+        return P(), P(None, axis), P(None, axis)
+    return P(None, axis), P(axis, None), P()
+
+
+def _like_output(a, tp):
+    """``a``, an (M, N) operand of the epilogue, held as the output is: its
+    columns over the axis for a column-parallel call, else replicated."""
+    return jax.lax.with_sharding_constraint(
+        a, NamedSharding(tp[0], _specs(tp)[2]))
+
+
+def _sharded(xq, wq, oflips, q_scale, dflips, imp, *, per_row, tp,
+             interpret):
+    """The kernel and its exchange in a ``shard_map`` over the mesh:
+    (M, N) int8-valued int32 outputs, divided as ``_specs`` says, and
+    ``t``."""
+    mesh, axis, split = tp
+    x_spec, w_spec, out_spec = _specs(tp)
+    qs = jnp.asarray(q_scale, jnp.int32).reshape(1, 1)
+    args, specs = [xq, wq, oflips, qs], [x_spec, w_spec, out_spec, P()]
+    if dflips is not None:
+        args += [dflips, imp]
+        specs += [out_spec, out_spec]
+
+    def body(x, w, of, q, *dppu):
+        m, n = x.shape[0], w.shape[1]
+        x8, w8 = _pad_to(x, (8, 128)), _pad_to(w, (128, 128))
+        if split == "column":
+            acc, amax = fused_decode(x8, w8, per_row=per_row, mode="acc",
+                                     interpret=interpret)
+            acc = acc[:m, :n]
+            with jax.named_scope("exchange"):
+                amax = jax.lax.pmax(amax[:m], axis)
+            if not per_row:
+                amax = amax[0, 0]
+        else:
+            part = fused_decode(x8, w8, per_row=per_row, mode="partial",
+                                interpret=interpret)
+            with jax.named_scope("exchange"):
+                acc = jax.lax.psum(part[:m, :n], axis)
+            acc = saturate(acc)
+            amax = absmax(acc, per_row)
+        kw = dict(dflips=dppu[0], imp=dppu[1]) if dppu else {}
+        return epilogue(acc, amax, q, of, **kw)
+
+    # unchecked: a Pallas call's outputs carry no record of the axes they
+    # vary over; ``t`` (and a row-parallel output) are whole after the
+    # exchange, the same on every chip
+    y, t = jax.shard_map(body, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=(out_spec, P()), check_vma=False)(*args)
+    return y.astype(jnp.int32), t

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.parallel import ctx as pctx
+from repro.parallel.sharding import tp_split
 
 
 def dtype_of(name: str):
@@ -137,7 +138,9 @@ def linear(x: jax.Array, w: jax.Array, b=None, *,
     """Every projection in the zoo routes through here — the integration point
     of the paper's technique (ft_linear) with the LM stack.  Its ops carry
     the ``linear`` named scope (the protected datapath ``linear/protect``),
-    which a profiler trace's HLO keeps."""
+    which a profiler trace's HLO keeps.  ``name`` keys the site's fault
+    stream and importance mask, and its last part (the weight's key) says
+    how a mesh divides ``w`` (``parallel.sharding.tp_split``)."""
     with jax.named_scope("linear"):
         return _linear(x, w, b, ftc=ftc, name=name)
 
@@ -179,7 +182,7 @@ def _linear(x, w, b, *, ftc, name):
                    w2, ftc.ft,
                    important=None if imp is None else jnp.asarray(imp),
                    layer_protected=prot, backend=ftc.backend,
-                   t=ftc.site_t(name), dyn=ftc.dyn)
+                   t=ftc.site_t(name), dyn=ftc.dyn, split=tp_split(name))
         y = y.reshape(*x.shape[:-1], *w.shape[1:]).astype(x.dtype)
     if b is not None:
         y = y + b.astype(y.dtype)

@@ -38,6 +38,16 @@ _OUT_PROJ = {"wo", "out_proj", "w_out"}
 _SQUARE = {"w_a", "w_i"}
 
 
+def tp_split(site: str) -> str | None:
+    """How ``param_spec`` divides a projection's 2-D weight over 'model',
+    from the name of its ``linear`` site, whose last part is the weight's
+    key: "column" for ``_IN_PROJ`` (its N), "row" for ``_OUT_PROJ`` (its
+    K), None for the rest."""
+    key = site.rsplit("/", 1)[-1]
+    return ("column" if key in _IN_PROJ else "row" if key in _OUT_PROJ
+            else None)
+
+
 def param_spec(path, leaf, mesh: Mesh) -> P:
     names = [k.key for k in path if isinstance(k, DictKey)]
     name = names[-1]
